@@ -152,4 +152,7 @@ def run(csv: CSV, dataset: str = "synthetic-10000", n_iters: int = 400, n_seeds:
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     run_step_rules(CSV())

@@ -64,4 +64,7 @@ def run(csv: CSV, dataset: str = "synthetic-10000"):
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     run(CSV())

@@ -46,4 +46,7 @@ def run(csv: CSV, dataset: str = "e2006-tfidf"):
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     run(CSV())

@@ -41,4 +41,7 @@ def run(csv: CSV):
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     run(CSV())

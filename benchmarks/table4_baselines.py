@@ -47,4 +47,7 @@ def run(csv: CSV, datasets=None):
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     run(CSV())

@@ -283,6 +283,7 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core import FWConfig, LASSO, engine
 from repro import distributed as dist
 from repro.data import make_regression, standardize
+from repro.devices import device_label
 from repro.sparse.matrix import SparseBlockMatrix
 
 m, p, n_iters, kappa = %(m)d, %(p)d, %(n_iters)d, %(kappa)d
@@ -320,15 +321,16 @@ for n_data, n_model in ((1, 4), (2, 2)):
         "local_bytes_per_iter": local,
         "comm_fraction": comm / (comm + local),
     }
-print("DISTRESULT" + json.dumps(rows))
+print("DISTRESULT" + json.dumps({"device": device_label(), "rows": rows}))
 """
 
 
 def _run_distributed_section(csv: CSV, js: BenchJSON):
     """Distributed-vs-single-device per-iteration time + analytic comm
     fraction on a forced 4-device CPU mesh. Runs in a subprocess so this
-    process keeps 1 device (DESIGN.md rule); skips gracefully when the
-    subprocess cannot run (constrained sandboxes)."""
+    process keeps 1 device (DESIGN.md rule). The child is pinned to the
+    CPU whatever the host has, and its rows carry the device it reports;
+    a failed child fails the benchmark."""
     import json as json_mod
     import os
     import subprocess
@@ -337,28 +339,31 @@ def _run_distributed_section(csv: CSV, js: BenchJSON):
     params = dict(m=256, p=4096, n_iters=300, kappa=64)
     if SCALE == "ci":
         params = dict(m=128, p=1024, n_iters=150, kappa=32)
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", _DIST_SCRIPT % params],
-            capture_output=True, text=True, timeout=1200,
-            env={**os.environ, "PYTHONPATH": os.pathsep.join(sys.path)},
+    proc = subprocess.run(
+        [sys.executable, "-c", _DIST_SCRIPT % params],
+        capture_output=True, text=True, timeout=1200,
+        env={**os.environ, "PYTHONPATH": os.pathsep.join(sys.path)},
+    )
+    lines = [l for l in proc.stdout.splitlines() if l.startswith("DISTRESULT")]
+    if proc.returncode != 0 or not lines:
+        raise RuntimeError(
+            f"distributed child failed (rc={proc.returncode}): "
+            f"{proc.stderr[-800:]}"
         )
-        lines = [l for l in proc.stdout.splitlines()
-                 if l.startswith("DISTRESULT")]
-        if proc.returncode != 0 or not lines:
-            raise RuntimeError(proc.stderr[-500:])
-    except Exception as exc:  # noqa: BLE001 - bench must not die here
-        csv.emit("table5/distributed/skipped", 0.0, f"reason={exc}")
-        return
-    rows = json_mod.loads(lines[0][len("DISTRESULT"):])
-    for name, row in rows.items():
+    out = json_mod.loads(lines[0][len("DISTRESULT"):])
+    for name, row in out["rows"].items():
         csv.emit(
             f"table5/distributed/{name}",
             row["seconds_per_iter"] * 1e6,
-            ";".join(f"{k}={v:.4g}" for k, v in row.items()),
+            f"device={out['device']};"
+            + ";".join(f"{k}={v:.4g}" for k, v in row.items()),
         )
-        js.add(f"table5/distributed/{name}", **params, **row)
+        js.add(f"table5/distributed/{name}", **params, **row,
+               device=out["device"])
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     run(CSV())

@@ -90,8 +90,9 @@ def main():
     # smaller scale in benchmarks/ — too expensive at p~10^6 for a demo.
     delta_max = 0.5 * float(np.abs(coef).sum())
     deltas = path_lib.delta_grid(delta_max, n_points=args.points)
-    # pallas wants aligned blocks (uniform degrades to width-1 bricks that
-    # leave the MXU idle — DESIGN.md §4.5); block sampling preserves Lemma 1
+    # pallas wants aligned blocks (uniform reads an 8-row slab per sampled
+    # row and leaves the MXU idle — DESIGN.md §4.5); block sampling
+    # preserves Lemma 1
     sampling = "block" if args.backend == "pallas" else "uniform"
     cfg = FWConfig(delta=1.0, kappa=kappa, sampling=sampling,
                    max_iters=5000, tol=1e-3, backend=args.backend)
@@ -112,4 +113,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     main()

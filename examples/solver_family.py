@@ -75,9 +75,12 @@ def main():
               f"obj {objs[0]:.3g} -> {objs[-1]:.3g}")
 
     # --- fused sparse colstats kernel (setup pass) ------------------------
+    from repro.devices import pallas_interpret
     from repro.sparse import ops as sops
 
-    zty_k, zn2_k = sops.sparse_colstats(mat, y, use_kernel=True, interpret=True)
+    zty_k, zn2_k = sops.sparse_colstats(
+        mat, y, use_kernel=True, interpret=pallas_interpret()
+    )
     zty_r, zn2_r = sops.sparse_colstats(mat, y)
     print("== fused sparse colstats kernel max |diff| vs XLA sweep:",
           float(jnp.max(jnp.abs(zty_k - zty_r))),
@@ -85,4 +88,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     main()

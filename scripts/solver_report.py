@@ -117,6 +117,7 @@ def _dist_child(args) -> None:
 
     from repro import distributed as dist
     from repro.core import LASSO
+    from repro.devices import device_label
     from repro.obs import ring_to_records
     from repro.sparse.matrix import SparseBlockMatrix
 
@@ -141,6 +142,8 @@ def _dist_child(args) -> None:
     entry = {
         "name": "lasso_distributed_1x4",
         "backend": "distributed",
+        # the child is pinned to 4 virtual CPU devices on any host
+        "device": device_label(),
         "iterations": int(res.iterations),
         "n_dots": int(res.n_dots),
         "objective": float(res.objective),
@@ -231,4 +234,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.devices import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -154,7 +154,6 @@ def precompute_colstats(
                 y,
                 use_kernel=vertex.use_sparse_kernel(cfg),
                 interpret=vertex.use_interpret(cfg),
-                gather_mode=vertex.resolve_gather_mode(cfg),
             )
         else:
             zty, znorm2 = sparse_ops.sparse_colstats(Xt, y)
@@ -507,7 +506,7 @@ def fused_chunk(oracle, Xt_run, y, stats, state: EngineState, cfg: FWConfig,
     executor is bit-identical by construction — chunked dispatch (and
     its K-fold stopping-check savings) is preserved either way."""
     needs_per_step = cfg.telemetry is not None and cfg.telemetry.record_objective
-    if vertex.use_fused_kernel(cfg) and not needs_per_step:
+    if vertex.use_fused_kernel(oracle, cfg) and not needs_per_step:
         return _fused_kernel_chunk(oracle, Xt_run, y, stats, state, cfg, delta)
     return _fused_ref_chunk(oracle, Xt_run, y, stats, state, cfg, delta)
 
@@ -749,9 +748,12 @@ def batched_result(oracle, Xt_run, y, stats, final, patience, cfg, deltas):
     objective = jax.vmap(lambda co: oracle.objective(y, stats, co, cfg))(final.co)
     gap = None
     if cfg.report_gap:
-        gap = jax.vmap(
-            lambda co, b, s, d: certified_gap(oracle, Xt_run, y, co, b, s, d, cfg)
-        )(final.co, final.beta, final.scale, deltas)
+        # one lane at a time: each gap is a full O(nnz) pass whose
+        # temporaries are matrix-sized, so a vmap would hold one per lane
+        gap = jax.lax.map(
+            lambda a: certified_gap(oracle, Xt_run, y, *a, cfg),
+            (final.co, final.beta, final.scale, deltas),
+        )
     return SolveResult(
         alpha=alpha,
         objective=objective,
@@ -793,8 +795,9 @@ def solve_batched(
     """
     vertex.check_matrix_backend(Xt, cfg)
     stats = precompute_colstats(Xt, y, cfg) if oracle.needs_stats else None
-    states0 = jax.vmap(lambda k, a0: init_state(oracle, Xt, y, k, a0, cfg))(
-        keys, alpha0s
+    # lane by lane, like the gaps: a warm start's X alpha0 is an O(nnz) pass
+    states0 = jax.lax.map(
+        lambda a: init_state(oracle, Xt, y, a[0], a[1], cfg), (keys, alpha0s)
     )
     patience = _patience(cfg)
     Xt_run = vertex.pad_backend_matrix(Xt, cfg)

@@ -61,12 +61,6 @@ class FWConfig:
       sparse_kernel: 'sparse' backend only — None = auto (Pallas
         kernels/sparse_grad on TPU, pure-XLA gather elsewhere), True/False
         forces the choice (tests force True + interpret).
-      gather_mode: how the sparse Pallas kernels read the VMEM-resident
-        residual/targets at the stored row indices: 'take' (in-kernel
-        jnp.take gather), 'onehot' (one-hot matmul fallback for TPUs where
-        the VMEM gather fails to lower — MXU-friendly, O(slots * m)
-        compute), or 'auto' (currently 'take'; the knob exists so a
-        failing lowering can be routed around without a code change).
       fuse_steps: K consecutive FW iterations per dispatch (DESIGN.md
         §Perf). 1 (default) is today's one-launch-per-iteration loop.
         K > 1 switches ``engine.run_loop``/``batched_loop`` to a chunked
@@ -131,7 +125,6 @@ class FWConfig:
     backend: str = "xla"
     fuse_steps: int = 1
     sparse_kernel: Optional[bool] = None
-    gather_mode: str = "auto"
     report_gap: bool = False
     m_tile: int = 512
     interpret: Optional[bool] = None

@@ -36,10 +36,13 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import devices
 from repro.core.solver_config import FWConfig
 from repro.kernels.fused_step import fused_step as _fused_step
+from repro.kernels.fw_grad.fw_grad import row_scores as _row_scores_kernel
 from repro.kernels.fw_grad.fw_grad import sampled_scores as _sampled_scores_kernel
 from repro.kernels.fw_grad.ops import fw_vertex as _fw_vertex_kernel
+from repro.kernels.lanes import SUBLANES
 from repro.kernels.padding import pad_rows as _pad_features
 from repro.kernels.residual_update.residual_update import (
     residual_update as _residual_update_kernel,
@@ -54,7 +57,7 @@ def use_interpret(cfg: FWConfig) -> bool:
     """Pallas kernels compile natively on TPU, interpret everywhere else."""
     if cfg.interpret is not None:
         return cfg.interpret
-    return jax.default_backend() != "tpu"
+    return devices.pallas_interpret()
 
 
 def use_sparse_kernel(cfg: FWConfig) -> bool:
@@ -62,20 +65,7 @@ def use_sparse_kernel(cfg: FWConfig) -> bool:
     (the XLA path is the production CPU path, not a test stub)."""
     if cfg.sparse_kernel is not None:
         return cfg.sparse_kernel
-    return jax.default_backend() == "tpu"
-
-
-def resolve_gather_mode(cfg: FWConfig) -> str:
-    """In-kernel VMEM read for the sparse Pallas kernels. 'auto' resolves
-    to the direct 'take' gather; 'onehot' is the explicit matmul fallback
-    for TPU targets where the gather fails to lower (ROADMAP item)."""
-    if cfg.gather_mode == "auto":
-        return "take"
-    if cfg.gather_mode not in ("take", "onehot"):
-        raise ValueError(
-            f"unknown gather_mode {cfg.gather_mode!r} (take|onehot|auto)"
-        )
-    return cfg.gather_mode
+    return not devices.pallas_interpret()
 
 
 def dist_spec(cfg: Optional[FWConfig]):
@@ -131,11 +121,15 @@ def check_matrix_backend(Xt, cfg: FWConfig) -> None:
 
 def pad_backend_matrix(Xt, cfg: FWConfig):
     """Zero-pad trailing feature rows for the dense kernel grids — once per
-    solve, OUTSIDE the hot loop (DESIGN.md §Padding). No-op for the other
-    backends ('sparse' pads at construction, 'xla' wraps modulo p)."""
-    if cfg.backend == "pallas" and cfg.sampling != "uniform":
-        return _pad_features(Xt, cfg.block_size)
-    return Xt
+    solve, OUTSIDE the hot loop (DESIGN.md §Padding): to whole blocks for
+    'block'/'full', to whole 8-row slabs for 'uniform' (the kernels read
+    a sampled row through its aligned slab). No-op for the other backends
+    ('sparse' pads at construction, 'xla' wraps modulo p)."""
+    if cfg.backend != "pallas":
+        return Xt
+    if cfg.sampling == "uniform":
+        return _pad_features(Xt, SUBLANES)
+    return _pad_features(Xt, cfg.block_size)
 
 
 # --------------------------------------------------------------------------
@@ -204,18 +198,24 @@ def _xla_vertex(Xt, w, key, p, cfg, extra_fn):
 def _kernel_vertex(Xt, w, key, p, cfg, extra_fn):
     """Sampled FW vertex via the Pallas scalar-prefetch gather kernel.
 
-    'block'/'full' drive block_size-wide aligned bricks; 'uniform' degrades
-    to width-1 blocks (same index stream as the XLA gather path). ``Xt``
-    may carry zero-padded trailing rows (indices >= p are masked out of
-    the argmax). Without an extra term the fused kernel argmax runs; with
-    one, the per-coordinate scores come back and the shift + argmax run
-    in XLA (the kernel reduction cannot see the extra term).
+    'block'/'full' drive block_size-wide aligned bricks; 'uniform' scores
+    each sampled row through its aligned 8-row slab (same index stream
+    and argmax as the XLA gather path). ``Xt`` may carry zero-padded
+    trailing rows (indices >= p are masked out of the argmax). Without an
+    extra term the fused kernel argmax runs; with one, the per-coordinate
+    scores come back and the shift + argmax run in XLA (the kernel
+    reduction cannot see the extra term).
     """
     if cfg.sampling == "uniform":
         # same draw as the XLA path: the backends replay one index stream
-        blk = sample_indices(key, p, cfg).astype(jnp.int32)
-        bs = 1
-    elif cfg.sampling == "block":
+        idx = sample_indices(key, p, cfg).astype(jnp.int32)
+        raw = _row_scores_kernel(
+            Xt, w, idx, m_tile=cfg.m_tile, interpret=use_interpret(cfg)
+        )
+        sel = raw if extra_fn is None else raw + extra_fn(idx)
+        j = jnp.argmax(jnp.abs(sel))
+        return idx[j], raw[j], sel[j], idx.shape[0]
+    if cfg.sampling == "block":
         blk = sample_block_starts(key, p, cfg)
         bs = cfg.block_size
     elif cfg.sampling == "full":
@@ -278,7 +278,6 @@ def _sparse_vertex(mat: SparseBlockMatrix, w, key, cfg, extra_fn):
         use_kernel=use_sparse_kernel(cfg),
         interpret=use_interpret(cfg),
         extra_fn=extra_fn,
-        gather_mode=resolve_gather_mode(cfg),
     )
     return i_star, g_raw, g_sel, n_scored
 
@@ -310,9 +309,8 @@ def score_indices(
     elif cfg.backend == "sparse":
         raw = sparse_ops.sparse_gather_scores(Xt, w, safe).astype(Xt.dtype)
     elif cfg.backend == "pallas":
-        raw = _sampled_scores_kernel(
-            Xt, w, safe, block_size=1, m_tile=cfg.m_tile,
-            interpret=use_interpret(cfg),
+        raw = _row_scores_kernel(
+            Xt, w, safe, m_tile=cfg.m_tile, interpret=use_interpret(cfg)
         )
     else:
         rows = jnp.take(Xt, safe, axis=0)  # (|idx|, m) row gather
@@ -398,14 +396,19 @@ def fused_supported(oracle, cfg: FWConfig) -> bool:
     return True
 
 
-def use_fused_kernel(cfg: FWConfig) -> bool:
+def use_fused_kernel(oracle, cfg: FWConfig) -> bool:
     """Chunk executor choice: the Pallas megakernel drives the 'pallas'
     backend and the kernel-dispatched 'sparse' backend; 'xla' and the
     XLA-gather sparse path chunk through a fori_loop over the unfused
-    engine step (bit-exact by construction)."""
-    if cfg.backend == "pallas":
-        return True
-    return cfg.backend == "sparse" and use_sparse_kernel(cfg)
+    engine step (bit-exact by construction). So does a chunk whose
+    scalar-prefetched K x kappa streams would not fit SMEM
+    (``fused_step.fits_smem``): the shape, not a fault, picks it."""
+    kernel_backend = cfg.backend == "pallas" or (
+        cfg.backend == "sparse" and use_sparse_kernel(cfg)
+    )
+    return kernel_backend and _fused_step.fits_smem(
+        cfg.fuse_steps, cfg.kappa, oracle.fused_needs_alpha
+    )
 
 
 def run_fused_kernel(
@@ -426,7 +429,7 @@ def run_fused_kernel(
     if isinstance(Xt, SparseBlockMatrix):
         return _fused_step.sparse_fused_chunk(
             Xt.values, Xt.rows, y, resid, scal, idx, zty_s, zn2_s, alpha_s,
-            k0, delta, gather_mode=resolve_gather_mode(cfg), **kw,
+            k0, delta, **kw,
         )
     return _fused_step.dense_fused_chunk(
         Xt, y, resid, scal, idx, zty_s, zn2_s, alpha_s, k0, delta, **kw

@@ -32,6 +32,8 @@ from repro.sparse.matrix import SparseBlockMatrix
 # Default dense-build budget (bytes); override per call or via env.
 DENSE_BUDGET_ENV = "REPRO_DENSE_BUDGET_BYTES"
 DEFAULT_DENSE_BUDGET = 2 << 30  # 2 GiB
+# host bytes of one band of the dense builder's mixing matrix
+_MIX_BAND_BYTES = 256 << 20
 
 
 class ProxySpec(NamedTuple):
@@ -115,11 +117,18 @@ def make_proxy(
     n = m + t
     if spec.col_density >= 1.0:
         # QSAR-like: dense, mildly correlated columns (product features).
-        base = rng.standard_normal((n, max(16, p // 64))).astype(np.float32)
-        mix = rng.standard_normal((base.shape[1], p)).astype(np.float32) / np.sqrt(
-            base.shape[1]
-        )
-        X = base @ mix + 0.5 * rng.standard_normal((n, p)).astype(np.float32)
+        q = max(16, p // 64)
+        base = rng.standard_normal((n, q)).astype(np.float32)
+        # the (q, p) mixing matrix is drawn a band of rows at a time (the
+        # same stream as one draw) so the published sizes need no
+        # q * p * 8-byte buffer: ~50 GB at triazines' p
+        band = max(1, _MIX_BAND_BYTES // (8 * p))
+        X = np.zeros((n, p))
+        for r0 in range(0, q, band):
+            rows = min(band, q - r0)
+            mix = rng.standard_normal((rows, p)).astype(np.float32) / np.sqrt(q)
+            X += base[:, r0 : r0 + rows] @ mix
+        X = X + 0.5 * rng.standard_normal((n, p)).astype(np.float32)
     else:
         # Text-like: sparse nonnegative counts, heavy-tailed.
         X = np.zeros((n, p), np.float32)

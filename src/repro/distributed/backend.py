@@ -123,7 +123,6 @@ def dist_sample_vertex(
             loc_blk,
             use_kernel=vertex.use_sparse_kernel(cfg),
             interpret=vertex.use_interpret(cfg),
-            gather_mode=vertex.resolve_gather_mode(cfg),
         ).reshape(nb_req, bs)
         raw = jax.lax.psum(
             jnp.where(own_blk[:, None], scores_l, 0.0), _both_axes(spec)

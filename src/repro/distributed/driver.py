@@ -28,7 +28,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import engine, path as path_lib, vertex
@@ -155,8 +154,9 @@ def _solver(mesh, oracle, cfg: FWConfig, geom, mode: str, warm: bool,
         def body(*args):
             *mat_args, y_l, keys, alpha0s, deltas = args
             Xt_l, stats = _prep(mat_args, y_l)
-            states0 = jax.vmap(lambda k, a0: _init(Xt_l, y_l, k, a0))(
-                keys, alpha0s
+            # lane by lane, as in engine.solve_batched (O(nnz) warm starts)
+            states0 = jax.lax.map(
+                lambda a: _init(Xt_l, y_l, a[0], a[1]), (keys, alpha0s)
             )
             final, saved = engine.batched_loop(
                 oracle, Xt_l, y_l, stats, states0, cfg, deltas, patience
@@ -266,8 +266,8 @@ def _solver(mesh, oracle, cfg: FWConfig, geom, mode: str, warm: bool,
     }[mode]
     n_operands = len(mat_specs) + n_extra
     in_specs = mat_specs + (P(da),) + (P(),) * (n_operands - len(mat_specs) - 1)
-    mapped = shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=P(), check_rep=False
+    mapped = jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
     return jax.jit(mapped)
 
@@ -551,12 +551,12 @@ def _gap_fn(mesh, oracle, cfg: FWConfig, geom):
         mat_specs = (
             P(spec.data_axis, spec.model_axis, None, None),
         ) * 2
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=mat_specs + (P(spec.data_axis), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 

@@ -7,6 +7,7 @@ colstats:         fused z^T y and ||z||^2 setup pass
 sparse_grad:      sampled block-ELL scores (sparse twin of fw_grad)
 sparse_colstats:  fused sparse z^T y and ||z||^2 (sparse twin of colstats)
 fused_step:       K fused FW iterations per launch, co-state VMEM-resident
+lanes:            in-kernel gather/scatter of an (m,) vector (lane layout)
 """
 from repro.kernels.fw_grad.ops import fw_vertex
 from repro.kernels.fw_grad.fw_grad import sampled_scores

@@ -15,11 +15,14 @@ pregenerated by the engine (a pure function of ``(key, cfg, p)``) and
 scalar-prefetched together with the per-coordinate column statistics
 (z^T y, ||z||^2) and — for the elastic-net — the chunk-start alpha
 values at the sampled coordinates. The BlockSpec index_map reads the
-prefetched stream, so each grid step DMAs exactly one sampled column
-(dense: a (1, m) row of Xt; sparse: a (1, 1, nnz_max) values+rows slot
-pair of the block-ELL layout — the ``sparse_grad`` brick scheme at
-width 1) while the argmax, line search, and state recursions never
-leave the core.
+prefetched stream, so each grid step DMAs the aligned 8-row slab that
+holds one sampled column (dense: an (8, m) slab of Xt; sparse: a
+(1, 8, nnz_max) values+rows slab of the block-ELL layout) and keeps that
+column's row — a single row is no legal block for the chip, whose
+block dims must be multiples of (8, 128) or equal to the array's —
+while the argmax, line search, and state recursions never leave the
+core. The sparse co-state lives in the lane layout of
+``kernels/lanes``, which is what makes its gather and scatter lower.
 
 Inside a chunk iteration (j sweeps the sample set, update at the last j):
 
@@ -55,10 +58,11 @@ reconstruction is mathematically exact but reassociates the unfused
 scale*beta products, so fused EN trajectories match to rounding rather
 than bitwise (DESIGN.md §Perf).
 
-VMEM budget per core (the fusion contract): the (m,) co-state, targets,
-and winner column (3m floats) + the K x |S| prefetched index/statistic
-streams + the K-slot ledger + scalars. beta (p,) and Xt never reside:
-m + K*|S| + K + O(1), independent of p.
+Memory per core (the fusion contract): the (m,) co-state, targets, and
+winner column (3m floats) in VMEM + the K-slot ledger + scalars; beta
+(p,) and Xt never reside. The K x |S| index and statistic streams are
+scalar-prefetched into SMEM, which is small: ``fits_smem`` bounds them,
+and the engine runs the per-step executor where they do not fit.
 
 Iterations past ``max_iters`` inside the trailing chunk are masked in
 kernel and replay (max_iters stays exact); the stall/patience rule is
@@ -74,30 +78,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sparse_grad.sparse_grad import gather_vmem
+from repro.kernels.lanes import (
+    SUBLANES,
+    from_lanes,
+    gather_lanes,
+    scatter_add_lanes,
+    to_lanes,
+)
+from repro.kernels.padding import pad_rows
 
-# step-record layout in the (K, 8) float output
+# step-record layout: N_REC floats per step in the flat SMEM record output
 REC_LAM, REC_DT, REC_RAW, REC_SEL, REC_STALL = 0, 1, 2, 3, 4
+N_REC = 8
+
+# Scalar-prefetch bytes the chunk may put in SMEM. v5e's SMEM is 1 MiB,
+# and its compiler refuses a chunk whose streams exceed it (K=8 at
+# kappa = 1% of e2006-log1p's p); a quarter stays for the kernel's own
+# scalars. K=8, kappa=8192 lasso streams fill the budget exactly.
+SMEM_PREFETCH_BUDGET = 768 * 1024
 
 
-def scatter_vmem(vec, rows, add, gather_mode: str):
-    """``vec.at[rows].add(add)`` inside a kernel — the write-side twin of
-    ``sparse_grad.gather_vmem``. 'take' is the direct scatter-add
-    (replays ``sparse.ops.sparse_residual_update``'s op sequence);
-    'onehot' rewrites it as a one-hot matmul for TPU targets where the
-    VMEM scatter fails to lower. Padded slots add 0.0 at row 0 either
-    way (structural no-op)."""
-    if gather_mode == "take":
-        return vec.at[rows].add(add)
-    if gather_mode == "onehot":
-        nnz = rows.shape[0]
-        m = vec.shape[0]
-        onehot = (
-            rows.reshape(nnz, 1)
-            == jax.lax.broadcasted_iota(jnp.int32, (nnz, m), 1)
-        ).astype(vec.dtype)
-        return vec + add @ onehot
-    raise ValueError(f"unknown gather_mode {gather_mode!r} (take|onehot)")
+def prefetch_bytes(K: int, kappa: int, needs_alpha: bool) -> int:
+    """SMEM bytes of the scalar-prefetched streams: the K x kappa index
+    stream, z^T y and ||z||^2 at it, and (elastic-net) alpha at it."""
+    return 4 * K * kappa * (4 if needs_alpha else 3)
+
+
+def fits_smem(K: int, kappa: int, needs_alpha: bool) -> bool:
+    """Whether a K-step chunk at this kappa fits the SMEM budget."""
+    return prefetch_bytes(K, kappa, needs_alpha) <= SMEM_PREFETCH_BUDGET
+
+
+def _select(vec, pos):
+    """``vec[pos]`` of an (n,) vector at a traced position: a masked sum
+    of one entry and zeros, so exact."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, vec.shape, 0) == pos
+    return jnp.sum(jnp.where(hit, vec, jnp.zeros_like(vec)))
+
+
+def _select_row(tile, pos):
+    """Row ``pos`` of an (n, w) tile as (1, w), exactly (masked sum)."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == pos
+    return jnp.sum(
+        jnp.where(hit, tile, jnp.zeros_like(tile)), axis=0, keepdims=True
+    )
 
 
 def _fused_kernel(
@@ -105,15 +129,15 @@ def _fused_kernel(
     idx_ref, zty_ref, zn2_ref, asamp_ref, int_ref,
     # SMEM input: [s_quad, f_lin, q_norm, delta]
     scal_ref,
-    # VMEM bricks (dense: x row; sparse: values + rows slots), then y, R0
+    # VMEM slabs (dense: Xt rows; sparse: values + rows slots), then y, R0
     *refs,
     oracle,
     layout: str,
+    slab: int,
     eps_den: float,
     gap_rtol: float,
     refresh_every: int,
     max_iters: int,
-    gather_mode: str,
 ):
     if layout == "dense":
         (x_ref, y_ref, r0_ref, stepf_ref, stepi_ref, rout_ref, sout_ref,
@@ -126,38 +150,42 @@ def _fused_kernel(
     n_steps = pl.num_programs(0)
     kappa = pl.num_programs(1)
     flat = s * kappa + j
+    my_idx = idx_ref[flat]
+    pos = my_idx % slab  # the sampled column's row within its slab
 
     @pl.when((s == 0) & (j == 0))
     def _init_chunk():
-        rout_ref[0, :] = r0_ref[0, :]
+        rout_ref[...] = r0_ref[...]
         acc[0] = scal_ref[0]  # S = ||X alpha||^2
         acc[1] = scal_ref[1]  # F = (X alpha)^T y
         acc[2] = scal_ref[2]  # Q = ||alpha||^2 (elastic-net)
         acc[3] = 1.0  # P = running prod(1 - lam) (alpha space)
         if oracle.fused_needs_alpha:
-            ladd[0, :] = jnp.zeros_like(ladd[0, :])
-            lidx[0, :] = jnp.full_like(lidx[0, :], -1)
+            ladd[...] = jnp.zeros_like(ladd)
+            lidx[...] = jnp.full_like(lidx, -1)
 
     @pl.when(j == 0)
     def _init_step():
         best[0] = -1.0  # running |selected score| (first-max argmax)
 
     # ---- score the j-th sampled coordinate against the live residual ----
-    resid = rout_ref[0, :]
     if layout == "dense":
-        row = x_ref[0, :].astype(jnp.float32)
-        raw = -jnp.dot(row, resid, preferred_element_type=jnp.float32)
+        x = x_ref[...].astype(jnp.float32)  # (slab, m)
+        # the unfused kernel's slab product, so the score rounds identically
+        scores = -jnp.dot(x, rout_ref[0, :], preferred_element_type=jnp.float32)
+        col = _select_row(x, pos)  # (1, m)
     else:
-        vals = vals_ref[0, 0, :].astype(jnp.float32)
-        rws = rows_ref[0, 0, :]
-        gathered = gather_vmem(resid, rws.reshape(1, -1), gather_mode)
-        # axis-1 reduce of the (1, nnz) row — the same reduction shape as
-        # the unfused sparse_gather_scores, so the score rounds identically
-        raw = -jnp.sum(vals.reshape(1, -1) * gathered, axis=1)[0]
+        vals = vals_ref[0].astype(jnp.float32)  # (slab, nnz_max)
+        rws = rows_ref[0]
+        # axis-1 reduce per slot row — the reduction shape of the unfused
+        # sparse_gather_scores, so the score rounds identically
+        scores = -jnp.sum(vals * gather_lanes(rout_ref, rws), axis=1)
+        col = _select_row(vals, pos)  # (1, nnz_max)
+        col_rows = _select_row(rws, pos)
+    raw = _select(scores, pos)
 
     if oracle.fused_needs_alpha:
-        my_idx = idx_ref[flat]
-        corr = jnp.sum(jnp.where(lidx[0, :] == my_idx, ladd[0, :], 0.0))
+        corr = jnp.sum(jnp.where(lidx[...] == my_idx, ladd[...], 0.0))
         a_i = acc[3] * asamp_ref[flat] + corr
         sel = raw + oracle.fused_score_shift(a_i)
     else:
@@ -171,12 +199,10 @@ def _fused_kernel(
     best[3] = jnp.where(better, zty_ref[flat], best[3])
     best[4] = jnp.where(better, zn2_ref[flat], best[4])
     best[5] = jnp.where(better, a_i, best[5])
-    ibest[0] = jnp.where(better, idx_ref[flat], ibest[0])
-    if layout == "dense":
-        zbuf[0, :] = jnp.where(better, row, zbuf[0, :])
-    else:
-        zbuf[0, :] = jnp.where(better, vals, zbuf[0, :])
-        zrows[0, :] = jnp.where(better, rws, zrows[0, :])
+    ibest[0] = jnp.where(better, my_idx, ibest[0])
+    zbuf[...] = jnp.where(better, col, zbuf[...])
+    if layout != "dense":
+        zrows[...] = jnp.where(better, col_rows, zrows[...])
 
     # ---- last sampled coordinate: line search + state recursions --------
     @pl.when(j == kappa - 1)
@@ -190,79 +216,78 @@ def _fused_kernel(
             scal3, g_raw, g_sel, a_star, delta_t, best[3], best[4],
             eps_den, gap_rtol,
         )
-        stepf_ref[0, REC_LAM] = lam
-        stepf_ref[0, REC_DT] = delta_t
-        stepf_ref[0, REC_RAW] = g_raw
-        stepf_ref[0, REC_SEL] = g_sel
-        stepf_ref[0, REC_STALL] = jnp.where(no_progress, 1.0, 0.0)
-        stepi_ref[0, 0] = ibest[0]
+        rec = s * N_REC
+        stepf_ref[rec + REC_LAM] = lam
+        stepf_ref[rec + REC_DT] = delta_t
+        stepf_ref[rec + REC_RAW] = g_raw
+        stepf_ref[rec + REC_SEL] = g_sel
+        stepf_ref[rec + REC_STALL] = jnp.where(no_progress, 1.0, 0.0)
+        stepi_ref[s] = ibest[0]
 
         @pl.when(k_glob < max_iters)
         def _apply():
             one_m = 1.0 - lam
-            r = rout_ref[0, :]
-            yv = y_ref[0, :]
+            r = rout_ref[...]
+            yv = y_ref[...]
             # eq. 10 co-state recursion, fused in VMEM (replays the
             # backend residual-update op sequence exactly)
             if layout == "dense":
-                rout_ref[0, :] = (1.0 - lam) * r + lam * (yv - delta_t * zbuf[0, :])
+                rout_ref[...] = (1.0 - lam) * r + lam * (yv - delta_t * zbuf[...])
             else:
-                out = (1.0 - lam) * r + lam * yv
-                rout_ref[0, :] = scatter_vmem(
-                    out, zrows[0, :], (-lam * delta_t) * zbuf[0, :], gather_mode
-                )
+                rout_ref[...] = (1.0 - lam) * r + lam * yv
+                scatter_add_lanes(rout_ref, zrows[...], (-lam * delta_t) * zbuf[...])
             ns, nf, nq = oracle.fused_scalar_update(
                 scal3, g_lin, a_star, lam, delta_t, best[3], best[4]
             )
             # periodic exact S/F refresh on the unfused cadence; the EN
             # Q refresh needs beta and is reconciled by the chunk driver
             refresh = (k_glob % refresh_every) == (refresh_every - 1)
-            v = yv - rout_ref[0, :]
-            ns = jnp.where(refresh, jnp.dot(v, v), ns)
-            nf = jnp.where(refresh, jnp.dot(v, yv), nf)
-            acc[0] = ns
-            acc[1] = nf
+            v = yv - rout_ref[...]
+            if layout == "dense":
+                # (1, m) @ (m,): a product the chip lowers, unlike 1-D dot
+                vv = jnp.dot(v, v[0], preferred_element_type=jnp.float32)[0]
+                vy = jnp.dot(v, yv[0], preferred_element_type=jnp.float32)[0]
+            else:
+                vv = jnp.sum(v * v)
+                vy = jnp.sum(v * yv)
+            acc[0] = jnp.where(refresh, vv, ns)
+            acc[1] = jnp.where(refresh, vy, nf)
             acc[2] = nq
             if oracle.fused_needs_alpha:
                 acc[3] = acc[3] * one_m
-                ladd[0, :] = ladd[0, :] * one_m
-                ladd[0, s] = lam * delta_t
-                lidx[0, s] = ibest[0]
+                lane = jax.lax.broadcasted_iota(jnp.int32, ladd.shape, 1)
+                ladd[...] = jnp.where(lane == s, lam * delta_t, ladd[...] * one_m)
+                lidx[...] = jnp.where(lane == s, ibest[0], lidx[...])
 
         @pl.when(s == n_steps - 1)
         def _emit_final():
-            sout_ref[0, 0] = acc[0]
-            sout_ref[0, 1] = acc[1]
-            sout_ref[0, 2] = acc[2]
+            sout_ref[0] = acc[0]
+            sout_ref[1] = acc[1]
+            sout_ref[2] = acc[2]
 
 
-def _unpack(outs):
-    stepf, stepi, rout, sout = outs
+def _call(kernel, grid_spec, K, res_shape, interpret, operands):
+    out_shape = (
+        jax.ShapeDtypeStruct((K * N_REC,), jnp.float32),  # per-step records
+        jax.ShapeDtypeStruct((K,), jnp.int32),  # i_star
+        jax.ShapeDtypeStruct(res_shape, jnp.float32),  # final residual
+        jax.ShapeDtypeStruct((N_REC,), jnp.float32),  # final (S, F, Q)
+    )
+    stepf, stepi, rout, sout = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+        name="fw_fused_step",
+    )(*operands)
+    stepf = stepf.reshape(K, N_REC)
     return (
-        stepi[:, 0],
+        stepi,
         stepf[:, REC_LAM],
         stepf[:, REC_DT],
         stepf[:, REC_STALL] > 0.5,
-        rout[0],
-        (sout[0, 0], sout[0, 1], sout[0, 2]),
-    )
-
-
-def _call(kernel, grid_spec, K, m, interpret, operands):
-    out_shape = (
-        jax.ShapeDtypeStruct((K, 8), jnp.float32),  # per-step records
-        jax.ShapeDtypeStruct((K, 1), jnp.int32),  # i_star
-        jax.ShapeDtypeStruct((1, m), jnp.float32),  # final residual
-        jax.ShapeDtypeStruct((1, 8), jnp.float32),  # final (S, F, Q)
-    )
-    return _unpack(
-        pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-            name="fw_fused_step",
-        )(*operands)
+        rout,
+        (sout[0], sout[1], sout[2]),
     )
 
 
@@ -279,32 +304,36 @@ def _common(scal, delta, k0):
     return scal_in, ints
 
 
-def _out_specs(m):
-    return [
-        pl.BlockSpec((1, 8), lambda s, j, *_: (s, 0)),
-        pl.BlockSpec((1, 1), lambda s, j, *_: (s, 0)),
-        pl.BlockSpec((1, m), lambda s, j, *_: (0, 0)),
-        pl.BlockSpec((1, 8), lambda s, j, *_: (0, 0)),
-    ]
+def _out_specs(res_shape):
+    """Records and final scalars in SMEM (scalar stores at step s); the
+    residual resident in VMEM across the whole grid."""
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return [smem, smem, pl.BlockSpec(res_shape, lambda s, j, *_: (0, 0)), smem]
 
 
-def _scratch(m, K, zrows_width):
-    """best/ibest/acc scalars + winner column + EN alpha-ledger. The
-    winner buffer is (1, m) dense or (1, nnz_max) sparse; ``zrows_width``
-    > 0 additionally allocates the sparse winner's row-index carry."""
+def _scratch(col_shape, K, sparse):
+    """best/ibest/acc scalars + winner column (+ its rows, sparse) + the
+    EN alpha-ledger."""
     shapes = [
         pltpu.SMEM((8,), jnp.float32),  # winner carry: mag/raw/sel/zty/zn2/a
         pltpu.SMEM((1,), jnp.int32),  # winner index
         pltpu.SMEM((8,), jnp.float32),  # S, F, Q, P accumulators
-        pltpu.VMEM((1, m), jnp.float32),  # winner column / values
+        pltpu.VMEM(col_shape, jnp.float32),  # winner column / values
     ]
-    if zrows_width:
-        shapes.append(pltpu.VMEM((1, zrows_width), jnp.int32))
+    if sparse:
+        shapes.append(pltpu.VMEM(col_shape, jnp.int32))  # winner rows
     shapes += [
         pltpu.VMEM((1, K), jnp.float32),  # ledger: correction values
         pltpu.VMEM((1, K), jnp.int32),  # ledger: touched coordinates
     ]
     return shapes
+
+
+def _streams(idx, zty_s, zn2_s, alpha_s, ints):
+    if alpha_s is None:
+        alpha_s = jnp.zeros((1,), jnp.float32)
+    return (idx.reshape(-1), zty_s.reshape(-1), zn2_s.reshape(-1),
+            alpha_s.reshape(-1), ints)
 
 
 def dense_fused_chunk(
@@ -331,8 +360,6 @@ def dense_fused_chunk(
     resid_out (m,), (S, F, Q))`` for the engine's replay."""
     K, kappa = idx.shape
     m = y.shape[0]
-    if alpha_s is None:
-        alpha_s = jnp.zeros((1,), jnp.float32)
     scal_in, ints = _common(scal, delta, k0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -340,37 +367,33 @@ def dense_fused_chunk(
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # scal_in
             pl.BlockSpec(
-                (1, m),
-                lambda s, j, idx, zty, zn2, asamp, ints: (idx[s * kappa + j], 0),
+                (SUBLANES, m),
+                lambda s, j, idx, *_: (idx[s * kappa + j] // SUBLANES, 0),
             ),
             pl.BlockSpec((1, m), lambda s, j, *_: (0, 0)),  # y
             pl.BlockSpec((1, m), lambda s, j, *_: (0, 0)),  # resid_in
         ],
-        out_specs=_out_specs(m),
-        scratch_shapes=_scratch(m, K, 0),
+        out_specs=_out_specs((1, m)),
+        scratch_shapes=_scratch((1, m), K, sparse=False),
     )
     kernel = functools.partial(
         _fused_kernel,
         oracle=oracle,
         layout="dense",
+        slab=SUBLANES,
         eps_den=eps_den,
         gap_rtol=gap_rtol,
         refresh_every=refresh_every,
         max_iters=max_iters,
-        gather_mode="take",
     )
-    operands = (
-        idx.reshape(-1),
-        zty_s.reshape(-1),
-        zn2_s.reshape(-1),
-        alpha_s.reshape(-1),
-        ints,
+    operands = _streams(idx, zty_s, zn2_s, alpha_s, ints) + (
         scal_in,
-        Xt.astype(jnp.float32),
+        pad_rows(Xt.astype(jnp.float32), SUBLANES),
         y.astype(jnp.float32).reshape(1, m),
         resid.astype(jnp.float32).reshape(1, m),
     )
-    return _call(kernel, grid_spec, K, m, interpret, operands)
+    out = _call(kernel, grid_spec, K, (1, m), interpret, operands)
+    return out[:4] + (out[4][0],) + out[5:]
 
 
 def sparse_fused_chunk(
@@ -391,60 +414,50 @@ def sparse_fused_chunk(
     gap_rtol: float,
     refresh_every: int,
     max_iters: int,
-    gather_mode: str = "take",
     interpret: bool = False,
 ):
     """K fused FW steps over the block-ELL sparse layout: grid step (s, j)
-    DMAs one (1, 1, nnz_max) values+rows slot pair (the ``sparse_grad``
-    scalar-prefetched brick scheme at width 1) and the winner's slots are
-    scatter-added into the VMEM residual (``gather_mode`` selects the
-    in-kernel gather/scatter form). Same return contract as the dense
-    twin."""
+    DMAs the (1, 8, nnz_max) values+rows slab holding the sampled feature
+    (the whole block when block_size is no multiple of 8), and the
+    winner's slots are scatter-added into the lane-layout VMEM residual.
+    Same return contract as the dense twin."""
     K, kappa = idx.shape
     m = y.shape[0]
     bs = values.shape[1]
     nnz_max = values.shape[2]
-    if alpha_s is None:
-        alpha_s = jnp.zeros((1,), jnp.float32)
+    slab = SUBLANES if bs % SUBLANES == 0 else bs
     scal_in, ints = _common(scal, delta, k0)
+    y2d = to_lanes(y)
 
-    def _slot(s, j, idx, *_):
+    def _slab(s, j, idx, *_):
         i = idx[s * kappa + j]
-        return (i // bs, i % bs, 0)
+        return (i // bs, (i % bs) // slab, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(K, kappa),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # scal_in
-            pl.BlockSpec((1, 1, nnz_max), _slot),  # values slots
-            pl.BlockSpec((1, 1, nnz_max), _slot),  # row-index slots
-            pl.BlockSpec((1, m), lambda s, j, *_: (0, 0)),  # y
-            pl.BlockSpec((1, m), lambda s, j, *_: (0, 0)),  # resid_in
+            pl.BlockSpec((1, slab, nnz_max), _slab),  # values slab
+            pl.BlockSpec((1, slab, nnz_max), _slab),  # row-index slab
+            pl.BlockSpec(y2d.shape, lambda s, j, *_: (0, 0)),  # y
+            pl.BlockSpec(y2d.shape, lambda s, j, *_: (0, 0)),  # resid_in
         ],
-        out_specs=_out_specs(m),
-        scratch_shapes=_scratch(nnz_max, K, nnz_max),
+        out_specs=_out_specs(y2d.shape),
+        scratch_shapes=_scratch((1, nnz_max), K, sparse=True),
     )
     kernel = functools.partial(
         _fused_kernel,
         oracle=oracle,
         layout="sparse",
+        slab=slab,
         eps_den=eps_den,
         gap_rtol=gap_rtol,
         refresh_every=refresh_every,
         max_iters=max_iters,
-        gather_mode=gather_mode,
     )
-    operands = (
-        idx.reshape(-1),
-        zty_s.reshape(-1),
-        zn2_s.reshape(-1),
-        alpha_s.reshape(-1),
-        ints,
-        scal_in,
-        values,
-        rows,
-        y.astype(jnp.float32).reshape(1, m),
-        resid.astype(jnp.float32).reshape(1, m),
+    operands = _streams(idx, zty_s, zn2_s, alpha_s, ints) + (
+        scal_in, values, rows, y2d, to_lanes(resid),
     )
-    return _call(kernel, grid_spec, K, m, interpret, operands)
+    out = _call(kernel, grid_spec, K, y2d.shape, interpret, operands)
+    return out[:4] + (from_lanes(out[4], m),) + out[5:]

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.lanes import SUBLANES
 from repro.kernels.padding import pad_rows
 
 
@@ -33,11 +34,11 @@ def _kernel(blk_ref, x_ref, r_ref, out_ref):
 
     @pl.when(j == 0)
     def _init():
-        out_ref[0, :] = partial
+        out_ref[0, 0, :] = partial
 
     @pl.when(j > 0)
     def _acc():
-        out_ref[0, :] = out_ref[0, :] + partial
+        out_ref[0, 0, :] = out_ref[0, 0, :] + partial
 
 
 @functools.partial(
@@ -58,7 +59,8 @@ def sampled_scores(
     ``p % block_size != 0`` zero-pads the trailing rows of ``Xt`` (padded
     coordinates score exactly 0 — callers that must never select them mask
     by global index, see ``ops.fw_vertex``), and ``m % m_tile != 0`` drops
-    to a single m tile.
+    to a single m tile. Scores come out as (nb, 1, block_size), so each
+    grid step writes a block whose last two dims equal the array's.
     """
     p, m = Xt.shape
     nb = blk.shape[0]
@@ -74,13 +76,35 @@ def sampled_scores(
             pl.BlockSpec((block_size, m_tile), lambda i, j, blk: (blk[i], j)),
             pl.BlockSpec((1, m_tile), lambda i, j, blk: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_size), lambda i, j, blk: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_size), lambda i, j, blk: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, block_size), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, block_size), jnp.float32),
         interpret=interpret,
         name="fw_sampled_scores",
     )(blk, Xt, r.reshape(1, m))
     return out.reshape(nb * block_size)
+
+
+@functools.partial(jax.jit, static_argnames=("m_tile", "interpret"))
+def row_scores(
+    Xt: jax.Array,  # (p, m) feature-major design matrix
+    r: jax.Array,  # (m,) residual
+    idx: jax.Array,  # (n,) int32 arbitrary row indices < p
+    *,
+    m_tile: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """Scores (n,) of arbitrary rows, in ``idx`` order ('uniform' sampling).
+
+    A single row is no legal block for the chip (its second-to-last dim
+    must be a multiple of 8), so each index scores the aligned 8-row slab
+    that holds it and keeps its own row's score."""
+    idx = idx.astype(jnp.int32)
+    slab = sampled_scores(
+        Xt, r, idx // SUBLANES, block_size=SUBLANES, m_tile=m_tile,
+        interpret=interpret,
+    ).reshape(idx.shape[0], SUBLANES)
+    return jnp.take_along_axis(slab, (idx % SUBLANES)[:, None], axis=1)[:, 0]

@@ -10,14 +10,18 @@ the solver precomputes once (paper §4.2),
 fused so the (block_size x nnz_max) values brick is read from HBM
 exactly once. The grid walks every feature block in order (a full sweep,
 so no scalar prefetch is needed — the index map IS the grid index); the
-targets vector y stays VMEM-resident (m floats, small by construction in
-the p >> m regime the paper targets) and the per-slot gather + two
+targets vector y stays VMEM-resident (m floats in the lane layout of
+``kernels/lanes``, small by construction in the p >> m regime the paper
+targets) and the per-slot gather + two
 reductions run on the VPU. Traffic is O(total stored slots) instead of
 the dense kernel's O(p * m).
 
 Padded ELL slots (value 0.0 at row 0) and padded tail features
 contribute exactly 0 to both outputs; the caller slices the feature
-padding off (same §Padding contract as the dense colstats kernel).
+padding off (same §Padding contract as the dense colstats kernel). Each
+block's statistics come out as a (1, 1, block_size) row of an
+(nblocks, 1, block_size) array, so every block shape the chip's compiler
+sees has last two dims equal to the array's.
 """
 from __future__ import annotations
 
@@ -27,48 +31,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.sparse_grad.sparse_grad import gather_vmem
+from repro.kernels.lanes import gather_lanes, to_lanes
 
 
-def _kernel(vals_ref, rows_ref, y_ref, zty_ref, zn2_ref, *, gather_mode):
+def _kernel(vals_ref, rows_ref, y_ref, zty_ref, zn2_ref):
     """One feature block: gather y at the stored rows, fused dual reduce."""
     vals = vals_ref[0].astype(jnp.float32)  # (block_size, nnz_max)
-    rows = rows_ref[0]  # (block_size, nnz_max) int32
-    y = y_ref[0].astype(jnp.float32)  # (m,)
-    gathered = gather_vmem(y, rows, gather_mode)  # (block_size, nnz_max)
-    zty_ref[0, :] = jnp.sum(vals * gathered, axis=1)
-    zn2_ref[0, :] = jnp.sum(vals * vals, axis=1)
+    gathered = gather_lanes(y_ref, rows_ref[0])  # (block_size, nnz_max)
+    zty_ref[0, 0, :] = jnp.sum(vals * gathered, axis=1)
+    zn2_ref[0, 0, :] = jnp.sum(vals * vals, axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "gather_mode"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def sparse_colstats_fused(
     values: jax.Array,  # (nblocks, block_size, nnz_max)
     rows: jax.Array,  # (nblocks, block_size, nnz_max) int32
     y: jax.Array,  # (m,) targets
     *,
     interpret: bool = False,
-    gather_mode: str = "take",
 ):
     """(zty, znorm2) of padded length nblocks * block_size, f32."""
     nblocks, block_size, nnz_max = values.shape
-    m = y.shape[0]
+    y2d = to_lanes(y)
+    stat = jax.ShapeDtypeStruct((nblocks, 1, block_size), jnp.float32)
     zty, zn2 = pl.pallas_call(
-        functools.partial(_kernel, gather_mode=gather_mode),
+        _kernel,
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((1, block_size, nnz_max), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, block_size, nnz_max), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
+            pl.BlockSpec(y2d.shape, lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_size), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_size), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, block_size), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, block_size), lambda i: (i, 0, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, block_size), jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, block_size), jnp.float32),
-        ],
+        out_shape=[stat, stat],
         interpret=interpret,
         name="fw_sparse_colstats",
-    )(values, rows, y.reshape(1, m))
+    )(values, rows, y2d)
     return zty.reshape(-1), zn2.reshape(-1)

@@ -10,8 +10,9 @@ The sampled block ids are scalar-prefetched exactly like the dense
 kernel: the BlockSpec index_map reads ``blk[i]``, so grid step i DMAs ONE
 (block_size x nnz_max) values brick and its row-index brick from HBM,
 gathers the referenced residual entries from the VMEM-resident residual
-(m floats — small by construction in the p >> m regime the paper
-targets), and segment-dots them on the VPU. Per grid step the kernel
+(m floats in the lane layout of ``kernels/lanes`` — small by
+construction in the p >> m regime the paper targets), and segment-dots
+them on the VPU. Per grid step the kernel
 reads O(block_size * nnz_max) instead of the dense kernel's
 O(block_size * m): at col_density 0.002 that is a ~500x traffic cut.
 
@@ -28,39 +29,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def gather_vmem(vec, rows, gather_mode: str):
-    """Read ``vec[rows]`` inside a kernel: (block_size, nnz_max) values of
-    the VMEM-resident (m,) vector at the stored row indices.
-
-    'take' is the direct gather; 'onehot' rewrites it as a one-hot matmul
-    (rows == iota compare, then MXU dot) — the fallback for TPU targets
-    where the VMEM gather fails to lower. Shared by sparse_grad and
-    sparse_colstats so both kernels survive the same hardware.
-    """
-    if gather_mode == "take":
-        return jnp.take(vec, rows, axis=0)
-    if gather_mode == "onehot":
-        bs, nnz = rows.shape
-        m = vec.shape[0]
-        onehot = (
-            rows.reshape(bs * nnz, 1)
-            == jax.lax.broadcasted_iota(jnp.int32, (bs * nnz, m), 1)
-        ).astype(vec.dtype)
-        return (onehot @ vec).reshape(bs, nnz)
-    raise ValueError(f"unknown gather_mode {gather_mode!r} (take|onehot)")
+from repro.kernels.lanes import gather_lanes, to_lanes
 
 
-def _kernel(blk_ref, vals_ref, rows_ref, r_ref, out_ref, *, gather_mode):
+def _kernel(blk_ref, vals_ref, rows_ref, r_ref, out_ref):
     """One sampled block: gather residual entries, segment-dot, negate."""
     vals = vals_ref[0].astype(jnp.float32)  # (block_size, nnz_max)
-    rows = rows_ref[0]  # (block_size, nnz_max) int32
-    r = r_ref[0].astype(jnp.float32)  # (m,)
-    gathered = gather_vmem(r, rows, gather_mode)  # (block_size, nnz_max)
-    out_ref[0, :] = -jnp.sum(vals * gathered, axis=1)
+    gathered = gather_lanes(r_ref, rows_ref[0])  # (block_size, nnz_max)
+    out_ref[0, 0, :] = -jnp.sum(vals * gathered, axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "gather_mode"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def sparse_sampled_scores(
     values: jax.Array,  # (nblocks, block_size, nnz_max)
     rows: jax.Array,  # (nblocks, block_size, nnz_max) int32
@@ -68,27 +47,29 @@ def sparse_sampled_scores(
     blk: jax.Array,  # (nb,) int32 sampled block indices
     *,
     interpret: bool = False,
-    gather_mode: str = "take",
 ) -> jax.Array:
-    """Scores (nb * block_size,) for the sampled feature blocks."""
+    """Scores (nb * block_size,) for the sampled feature blocks.
+
+    The score rows come out as (nb, 1, block_size), so each grid step
+    writes a block whose last two dims equal the array's."""
     _, block_size, nnz_max = values.shape
     nb = blk.shape[0]
-    m = r.shape[0]
+    r2d = to_lanes(r)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, block_size, nnz_max), lambda i, blk: (blk[i], 0, 0)),
             pl.BlockSpec((1, block_size, nnz_max), lambda i, blk: (blk[i], 0, 0)),
-            pl.BlockSpec((1, m), lambda i, blk: (0, 0)),
+            pl.BlockSpec(r2d.shape, lambda i, blk: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_size), lambda i, blk: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_size), lambda i, blk: (i, 0, 0)),
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, gather_mode=gather_mode),
+        _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, block_size), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, block_size), jnp.float32),
         interpret=interpret,
         name="fw_sparse_sampled_scores",
-    )(blk, values, rows, r.reshape(1, m))
+    )(blk, values, rows, r2d)
     return out.reshape(nb * block_size)

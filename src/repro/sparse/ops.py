@@ -35,20 +35,16 @@ def sparse_block_scores(
     *,
     use_kernel: bool = False,
     interpret: bool = False,
-    gather_mode: str = "take",
 ) -> jax.Array:
     """FW scores (-z_i^T R) for the features of the sampled blocks.
 
     ``use_kernel`` routes through the Pallas scalar-prefetch kernel
     (``kernels/sparse_grad``); otherwise the pure-XLA oracle runs — the
-    off-TPU production path, not just a test double. ``gather_mode``
-    selects the in-kernel residual read ('take' gather vs the 'onehot'
-    matmul fallback); the XLA oracle always gathers.
+    off-TPU production path, not just a test double.
     """
     if use_kernel:
         return sparse_sampled_scores(
-            mat.values, mat.rows, resid, blk, interpret=interpret,
-            gather_mode=gather_mode,
+            mat.values, mat.rows, resid, blk, interpret=interpret
         )
     return sparse_sampled_scores_ref(mat.values, mat.rows, resid, blk)
 
@@ -61,7 +57,6 @@ def sparse_fw_vertex_general(
     use_kernel: bool = False,
     interpret: bool = False,
     extra_fn: Optional[ExtraFn] = None,
-    gather_mode: str = "take",
 ):
     """(i_star, g_raw, g_sel) over the sampled blocks, masking padding.
 
@@ -75,8 +70,7 @@ def sparse_fw_vertex_general(
     gathers for padded idx >= p, which the mask makes unselectable.
     """
     scores = sparse_block_scores(
-        mat, w, blk, use_kernel=use_kernel, interpret=interpret,
-        gather_mode=gather_mode,
+        mat, w, blk, use_kernel=use_kernel, interpret=interpret
     )
     idx = (
         blk[:, None] * mat.block_size + jnp.arange(mat.block_size)[None, :]
@@ -105,10 +99,9 @@ def sparse_fw_vertex(
 
 def sparse_gather_scores(mat: SparseBlockMatrix, w: jax.Array, idx: jax.Array):
     """Raw f32 scores -z_i^T w for arbitrary sampled coordinates
-    ('uniform' mode). Width-1 gathers have no aligned-block structure to
-    prefetch, so this is XLA-only (mirroring how the dense kernel path
-    degrades uniform sampling to width-1 bricks). ``idx`` entries are
-    < p by construction."""
+    ('uniform' mode). Single-feature gathers have no aligned-block
+    structure to prefetch, so this is XLA-only. ``idx`` entries are < p by
+    construction."""
     b = idx // mat.block_size
     t = idx % mat.block_size
     vals = mat.values[b, t].astype(jnp.float32)  # (kappa, nnz_max)
@@ -143,7 +136,6 @@ def sparse_colstats(
     *,
     use_kernel: bool = False,
     interpret: bool = False,
-    gather_mode: str = "take",
 ):
     """One pass over the stored slots: z_i^T y and ||z_i||^2 (paper §4.2).
 
@@ -156,8 +148,7 @@ def sparse_colstats(
     """
     if use_kernel:
         zty_pad, zn2_pad = sparse_colstats_fused(
-            mat.values, mat.rows, y, interpret=interpret,
-            gather_mode=gather_mode,
+            mat.values, mat.rows, y, interpret=interpret
         )
         return (
             zty_pad[: mat.p].astype(mat.dtype),

@@ -186,7 +186,7 @@ class TestSolverFamilySparseParity:
         assert rel < 1e-4
 
     def test_logistic_pallas_matches_xla(self, rng_key):
-        """'uniform' replays the XLA index stream through the width-1
+        """'uniform' replays the XLA index stream through the 8-row-slab
         kernel path; 'full' is deterministic modulo tail padding."""
         Xt, y = _logistic_data(p=300)
         for sampling, kw, tol in (

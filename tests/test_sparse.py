@@ -194,53 +194,79 @@ class TestSparseKernel:
         assert got.dtype == jnp.float32  # f32 accumulation contract
 
 
-class TestGatherMode:
-    """gather_mode='onehot' — the one-hot matmul fallback for TPUs where
-    the in-kernel VMEM ``jnp.take`` fails to lower (ISSUE 4 satellite) —
-    must agree with the 'take' gather in BOTH sparse kernels."""
+class TestLaneGather:
+    """The in-kernel gather/scatter of ``kernels/lanes`` (the form the
+    chip's compiler accepts: the vector in (ceil(m/128), 128) lane layout,
+    one lane-gather per sublane row) must match plain indexing exactly,
+    across several lane rows (m > 128) and several lane chunks
+    (nnz_max > 128)."""
 
-    @pytest.mark.parametrize("p,m,bs", [(300, 80, 128), (130, 70, 32)])
-    def test_sampled_scores_take_vs_onehot(self, p, m, bs):
-        _, mat, r = _sparse_dense_pair(p, m, 0.05, seed=p, block_size=bs)
+    @pytest.mark.parametrize("p,m,bs,density", [(300, 80, 128, 0.05),
+                                                (130, 700, 32, 0.3)])
+    def test_sampled_scores_multi_lane(self, p, m, bs, density):
+        _, mat, r = _sparse_dense_pair(p, m, density, seed=p, block_size=bs)
         blk = jnp.arange(mat.nblocks, dtype=jnp.int32)
-        take = sparse_sampled_scores(mat.values, mat.rows, jnp.asarray(r),
-                                     blk, interpret=True, gather_mode="take")
-        onehot = sparse_sampled_scores(mat.values, mat.rows, jnp.asarray(r),
-                                       blk, interpret=True, gather_mode="onehot")
-        np.testing.assert_allclose(np.asarray(take), np.asarray(onehot),
+        got = sparse_sampled_scores(mat.values, mat.rows, jnp.asarray(r),
+                                    blk, interpret=True)
+        want = sparse_sampled_scores_ref(mat.values, mat.rows,
+                                         jnp.asarray(r), blk)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-5)
 
-    def test_colstats_take_vs_onehot(self):
-        _, mat, r = _sparse_dense_pair(130, 70, 0.1, seed=9, block_size=32)
+    def test_colstats_kernel_matches_xla(self):
+        _, mat, r = _sparse_dense_pair(130, 700, 0.3, seed=9, block_size=32)
+        assert mat.nnz_max > 128 and mat.m > 128
         y = jnp.asarray(r)
-        z_t, n_t = sops.sparse_colstats(mat, y, use_kernel=True,
-                                        interpret=True, gather_mode="take")
-        z_o, n_o = sops.sparse_colstats(mat, y, use_kernel=True,
-                                        interpret=True, gather_mode="onehot")
-        np.testing.assert_allclose(np.asarray(z_t), np.asarray(z_o),
+        z_k, n_k = sops.sparse_colstats(mat, y, use_kernel=True, interpret=True)
+        z_x, n_x = sops.sparse_colstats(mat, y, use_kernel=False)
+        np.testing.assert_allclose(np.asarray(z_k), np.asarray(z_x),
                                    rtol=1e-6, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(n_t), np.asarray(n_o),
+        np.testing.assert_allclose(np.asarray(n_k), np.asarray(n_x),
                                    rtol=1e-6, atol=1e-5)
 
-    def test_solver_end_to_end_onehot(self, sparse_problem, rng_key):
-        """FWConfig.gather_mode plumbs through the solver hot loop."""
+    def test_solver_end_to_end_kernel_vs_xla(self, sparse_problem, rng_key):
+        """Block sampling draws the same blocks either way, so the kernel
+        and the XLA gather solve the same problem to rounding."""
         _, mat, y = sparse_problem
         base = dict(delta=DELTA, sampling="block", kappa=128, max_iters=1500,
-                    tol=1e-4, backend="sparse", sparse_kernel=True,
-                    interpret=True)
-        res_t = fw_solve(mat, y, FWConfig(gather_mode="take", **base), rng_key)
-        res_o = fw_solve(mat, y, FWConfig(gather_mode="onehot", **base), rng_key)
-        rel = abs(float(res_o.objective) - float(res_t.objective)) / abs(
-            float(res_t.objective)
+                    tol=1e-4, backend="sparse", interpret=True)
+        res_k = fw_solve(mat, y, FWConfig(sparse_kernel=True, **base), rng_key)
+        res_x = fw_solve(mat, y, FWConfig(sparse_kernel=False, **base), rng_key)
+        rel = abs(float(res_k.objective) - float(res_x.objective)) / abs(
+            float(res_x.objective)
         )
         assert rel < 1e-4
 
-    def test_unknown_mode_rejected(self):
-        _, mat, r = _sparse_dense_pair(64, 32, 0.2, seed=1, block_size=32)
-        with pytest.raises(ValueError, match="gather_mode"):
-            sparse_sampled_scores(mat.values, mat.rows, jnp.asarray(r),
-                                  jnp.asarray([0], jnp.int32),
-                                  interpret=True, gather_mode="bogus")
+    def test_gather_and_scatter_are_exact(self):
+        from jax.experimental import pallas as pl
+
+        from repro.kernels import lanes
+
+        rng = np.random.default_rng(3)
+        m, C = 300, 140
+        v = rng.standard_normal(m).astype(np.float32)
+        rows = rng.integers(0, m, (8, C)).astype(np.int32)
+        scat = rng.permutation(m)[:C].astype(np.int32)[None, :]
+        add = rng.standard_normal((1, C)).astype(np.float32)
+
+        def kernel(v_ref, rows_ref, srows_ref, add_ref, g_ref, out_ref):
+            g_ref[...] = lanes.gather_lanes(v_ref, rows_ref[...])
+            out_ref[...] = v_ref[...]
+            lanes.scatter_add_lanes(out_ref, srows_ref[...], add_ref[...])
+
+        v2d = lanes.to_lanes(jnp.asarray(v))
+        g, out = pl.pallas_call(
+            kernel,
+            out_shape=(jax.ShapeDtypeStruct((8, C), jnp.float32),
+                       jax.ShapeDtypeStruct(v2d.shape, jnp.float32)),
+            interpret=True,
+        )(v2d, jnp.asarray(rows), jnp.asarray(scat), jnp.asarray(add))
+        np.testing.assert_array_equal(np.asarray(g), v[rows])
+        want = v.copy()
+        want[scat[0]] += add[0]
+        np.testing.assert_array_equal(
+            np.asarray(lanes.from_lanes(out, m)), want
+        )
 
 
 class TestSolverParity:
