@@ -1,0 +1,10 @@
+"""Idle share (%) of the device over the profiler trace of the traced fits:
+1 - (union of device operation intervals) / window."""
+from bench import trace
+
+
+def read(ctx):
+    if ctx.entry != "fit" or ctx.trace is None:
+        return None
+    idle = trace.idle_share(ctx.trace)
+    return None if idle is None else 100.0 * idle
