@@ -77,7 +77,9 @@ import numpy as np
 from repro.core import vertex
 from repro.core.solver_config import FWConfig
 from repro.obs import metrics as obs_metrics
+from repro.obs import phases as obs_phases
 from repro.obs import telemetry as obs_telemetry
+from repro.obs import trace as obs_trace
 from repro.resilience import validate as _validate
 from repro.kernels.colstats.colstats import colstats as _colstats_kernel
 from repro.sparse import ops as sparse_ops
@@ -136,6 +138,7 @@ class SolveResult(NamedTuple):
     telemetry: Optional[Any] = None
 
 
+@obs_phases.scoped("fw/colstats")
 def precompute_colstats(
     Xt, y: jax.Array, cfg: Optional[FWConfig] = None
 ) -> ColStats:
@@ -186,6 +189,7 @@ def dot_dtype():
     return jnp.int64 if jax.config.jax_enable_x64 else jnp.float32
 
 
+@obs_phases.scoped("fw/init")
 def init_state(oracle, Xt, y, key, alpha0=None, cfg=None, p=None) -> EngineState:
     """Start from the null solution, or warm-start from ``alpha0``.
 
@@ -271,59 +275,61 @@ def step(oracle, Xt, y, stats, state: EngineState, cfg: FWConfig, delta) -> Engi
     feature-padded (``vertex.pad_backend_matrix``); ``beta`` and
     ``stats`` stay at the true p.
     """
-    p = state.beta.shape[0]
-    key, sub = jax.random.split(state.key)
+    with jax.named_scope("fw/loop/score"):
+        p = state.beta.shape[0]
+        key, sub = jax.random.split(state.key)
 
-    # -- step 2: score the sampled coordinates against the co-gradient ------
-    w = oracle.cograd(state.co, y)
-    extra_fn = oracle.score_extra(state.beta, state.scale)
-    i_star, g_raw, g_sel, n_scored = vertex.sample_vertex(
-        Xt, w, sub, p, cfg, extra_fn
-    )
-
-    # -- step 3: FW vertex sign (eq. 6) -------------------------------------
-    delta_t = -delta * jnp.sign(g_sel)  # delta-tilde
-
-    # -- step 4: oracle line search (closed-form eq. 8, or bisection) -------
-    a_star = state.scale * state.beta[i_star]
-    lam, no_progress, aux = oracle.line_search(
-        Xt, y, stats, state.co, i_star, g_raw, g_sel, a_star, delta_t, cfg
-    )
-
-    # -- step 5 + §Stopping statistics (shared with the fused replay) ------
-    beta, scale, maxabs, step_inf, stall = apply_coeff_update(
-        state.beta, state.scale, state.maxabs, state.stall, a_star, i_star,
-        lam, delta_t, no_progress, cfg,
-    )
-
-    # -- step 6: oracle state recursions (eq. 10 / margin + S/F/Q + refresh)
-    co = oracle.update_co(
-        Xt, y, stats, state.co, beta, scale, i_star, a_star, lam, delta_t,
-        state.k, cfg, aux,
-    )
-
-    n_dots = state.n_dots + n_scored + oracle.extra_dots
-    tel = state.tel
-    if cfg is not None and cfg.telemetry is not None:
-        # sampled FW duality gap -<grad, delta_t e_i - alpha> =
-        # <grad, alpha> - delta_t * sel_i — for the closed-form oracles
-        # this IS the line-search numerator (O(1) scalars; logistic pays
-        # one O(m) reduction per recorded objective)
-        if cfg.telemetry.record_objective:
-            gap = (
-                oracle.grad_dot_alpha(
-                    state.co, stats, y, state.beta, state.scale, cfg
-                )
-                - delta_t * g_sel
-            )
-            objective = oracle.objective(y, stats, co, cfg)
-        else:
-            gap = objective = jnp.nan
-        tel = obs_telemetry.record(
-            tel, k=state.k, i_star=i_star, event=obs_telemetry.EVENT_FW,
-            lam=lam, gap=gap, objective=objective, step_inf=step_inf,
-            stall=stall, n_dots=n_dots,
+        # -- step 2: score the sampled coordinates against the co-gradient --
+        w = oracle.cograd(state.co, y)
+        extra_fn = oracle.score_extra(state.beta, state.scale)
+        i_star, g_raw, g_sel, n_scored = vertex.sample_vertex(
+            Xt, w, sub, p, cfg, extra_fn
         )
+
+        # -- step 3: FW vertex sign (eq. 6) ---------------------------------
+        delta_t = -delta * jnp.sign(g_sel)  # delta-tilde
+    with jax.named_scope("fw/loop/update"):
+        # -- step 4: oracle line search (closed-form eq. 8, or bisection) ---
+        a_star = state.scale * state.beta[i_star]
+        lam, no_progress, aux = oracle.line_search(
+            Xt, y, stats, state.co, i_star, g_raw, g_sel, a_star, delta_t, cfg
+        )
+
+        # -- step 5 + §Stopping statistics (shared with the fused replay) --
+        beta, scale, maxabs, step_inf, stall = apply_coeff_update(
+            state.beta, state.scale, state.maxabs, state.stall, a_star, i_star,
+            lam, delta_t, no_progress, cfg,
+        )
+
+        # -- step 6: oracle state recursions (eq. 10 / margin + S/F/Q +
+        # refresh)
+        co = oracle.update_co(
+            Xt, y, stats, state.co, beta, scale, i_star, a_star, lam, delta_t,
+            state.k, cfg, aux,
+        )
+
+        n_dots = state.n_dots + n_scored + oracle.extra_dots
+        tel = state.tel
+        if cfg is not None and cfg.telemetry is not None:
+            # sampled FW duality gap -<grad, delta_t e_i - alpha> =
+            # <grad, alpha> - delta_t * sel_i — for the closed-form oracles
+            # this IS the line-search numerator (O(1) scalars; logistic pays
+            # one O(m) reduction per recorded objective)
+            if cfg.telemetry.record_objective:
+                gap = (
+                    oracle.grad_dot_alpha(
+                        state.co, stats, y, state.beta, state.scale, cfg
+                    )
+                    - delta_t * g_sel
+                )
+                objective = oracle.objective(y, stats, co, cfg)
+            else:
+                gap = objective = jnp.nan
+            tel = obs_telemetry.record(
+                tel, k=state.k, i_star=i_star, event=obs_telemetry.EVENT_FW,
+                lam=lam, gap=gap, objective=objective, step_inf=step_inf,
+                stall=stall, n_dots=n_dots,
+            )
 
     return EngineState(
         beta=beta,
@@ -362,6 +368,7 @@ def rule_step(oracle, Xt, y, stats, state: EngineState, cfg: FWConfig,
 # --------------------------------------------------------------------------
 
 
+@obs_phases.scoped("fw/loop/score")
 def _fused_streams(oracle, stats, state: EngineState, cfg: FWConfig, p: int):
     """Pregenerate the chunk's K x kappa uniform index stream — replaying
     the unfused per-step (split, randint) chain exactly, so the stream
@@ -383,6 +390,7 @@ def _fused_streams(oracle, stats, state: EngineState, cfg: FWConfig, p: int):
     return key_new, idx, zty_s, zn2_s, alpha_s
 
 
+@obs_phases.scoped("fw/loop/update")
 def _fused_replay(oracle, state: EngineState, cfg: FWConfig, i_stars, lams,
                   delta_ts, no_progs):
     """Replay the kernel's per-step records into the O(p) coefficient
@@ -495,6 +503,7 @@ def _fused_ref_chunk(oracle, Xt_run, y, stats, state: EngineState,
     return jax.lax.fori_loop(0, cfg.fuse_steps, body, state)
 
 
+@obs_phases.scoped("fw/loop")
 def fused_chunk(oracle, Xt_run, y, stats, state: EngineState, cfg: FWConfig,
                 delta) -> EngineState:
     """Advance K = cfg.fuse_steps iterations in one dispatch (megakernel
@@ -511,6 +520,7 @@ def fused_chunk(oracle, Xt_run, y, stats, state: EngineState, cfg: FWConfig,
     return _fused_ref_chunk(oracle, Xt_run, y, stats, state, cfg, delta)
 
 
+@obs_phases.scoped("fw/gap")
 def certified_gap(oracle, Xt, y, co, beta, scale, delta, cfg=None) -> jax.Array:
     """Exact FW duality gap g(alpha) = alpha^T grad + delta*||grad||_inf
     from a live co-state — one full-gradient pass (O(nnz) sparse,
@@ -544,6 +554,7 @@ def oracle_gap(oracle, Xt, y, alpha, delta, cfg=None) -> jax.Array:
     )
 
 
+@obs_phases.scoped("fw/loop")
 def run_loop(oracle, Xt_run, y, stats, state0, cfg, delta, patience):
     """The sequential while_loop shared by ``solve`` and the distributed
     driver: step until the §Stopping rule fires or max_iters.
@@ -602,6 +613,7 @@ def _effective_fuse_steps(oracle, cfg) -> int:
     return cfg.fuse_steps if vertex.fused_supported(oracle, cfg) else 1
 
 
+@obs_phases.scoped("fw/result")
 def _result(
     oracle, Xt, y, stats, final: EngineState, patience: int, cfg, delta
 ) -> SolveResult:
@@ -702,6 +714,7 @@ def _lane_mask(active: jax.Array, leaf: jax.Array) -> jax.Array:
     return active.reshape(active.shape + (1,) * (leaf.ndim - 1))
 
 
+@obs_phases.scoped("fw/loop")
 def batched_loop(oracle, Xt_run, y, stats, states0, cfg, deltas, patience):
     """The lane-pruned while_loop shared by ``solve_batched`` and the
     distributed driver (repro.distributed.driver runs it inside its
@@ -741,6 +754,7 @@ def batched_loop(oracle, Xt_run, y, stats, states0, cfg, deltas, patience):
     return jax.lax.while_loop(cond, body, (states0, jnp.zeros((), jnp.int32)))
 
 
+@obs_phases.scoped("fw/result")
 def batched_result(oracle, Xt_run, y, stats, final, patience, cfg, deltas):
     """Assemble the per-lane SolveResult (shared with the distributed
     driver); certified per-lane gaps when ``cfg.report_gap``."""
@@ -796,9 +810,10 @@ def solve_batched(
     vertex.check_matrix_backend(Xt, cfg)
     stats = precompute_colstats(Xt, y, cfg) if oracle.needs_stats else None
     # lane by lane, like the gaps: a warm start's X alpha0 is an O(nnz) pass
-    states0 = jax.lax.map(
-        lambda a: init_state(oracle, Xt, y, a[0], a[1], cfg), (keys, alpha0s)
-    )
+    with jax.named_scope("fw/init"):
+        states0 = jax.lax.map(
+            lambda a: init_state(oracle, Xt, y, a[0], a[1], cfg), (keys, alpha0s)
+        )
     patience = _patience(cfg)
     Xt_run = vertex.pad_backend_matrix(Xt, cfg)
     final, saved = batched_loop(
@@ -887,19 +902,22 @@ def _observe_solve(reg, entry: str, cfg: FWConfig, res: SolveResult,
 class _MetricsEntry:
     """Host shim over a jitted solver entry point.
 
-    With no registry installed (the default) this is a straight
-    pass-through — the compiled program and its dispatch path are
-    untouched, which is what keeps the metrics-off contract as strong as
-    the telemetry-off one. With a registry installed it times the
-    dispatch to completion (``block_until_ready`` — jit calls return
-    asynchronously) and folds totals/latency/gap into the registry.
-    jit attributes (``_cache_size``, ``clear_cache``, ``lower``, ...)
-    forward to the wrapped function, so cache bookkeeping like
-    ``path.batched_solver_cache_size`` keeps working."""
+    Every dispatch runs inside a tracer span named after the entry (on
+    the profiler's clock, ``obs.trace``) and hands its abstract
+    signature to ``obs.phases.remember``, so a profile can name the
+    program's ops by phase after the fact; neither touches the compiled
+    program. With no registry installed (the default) the dispatch is
+    otherwise a straight pass-through. With a registry installed it
+    times the dispatch to completion (``block_until_ready`` — jit calls
+    return asynchronously) and folds totals/latency/gap into the
+    registry. jit attributes (``_cache_size``, ``clear_cache``,
+    ``lower``, ...) forward to the wrapped function, so cache
+    bookkeeping like ``path.batched_solver_cache_size`` keeps working."""
 
-    def __init__(self, fn, entry: str):
+    def __init__(self, fn, entry: str, static=("oracle", "cfg")):
         self._fn = fn
         self._entry = entry
+        self._static = static
         self.__name__ = entry
         self.__doc__ = fn.__doc__
         self.__wrapped__ = fn
@@ -909,15 +927,18 @@ class _MetricsEntry:
         # poisoned matrix otherwise burns a silent max_iters run
         # (resilience/validate.py; REPRO_SKIP_INPUT_VALIDATION=1 opts out)
         _validate.validate_inputs(Xt, y)
+        obs_phases.remember(self._entry, self._fn, (oracle, Xt, y, cfg) + args,
+                            kwargs, self._static)
         reg = obs_metrics.get_registry()
-        if reg is None:
-            return self._fn(oracle, Xt, y, cfg, *args, **kwargs)
-        t0 = time.perf_counter()
-        out = self._fn(oracle, Xt, y, cfg, *args, **kwargs)
-        # solve returns a bare SolveResult; the history/batched entries
-        # return (SolveResult, extra) — and SolveResult is itself a tuple
-        res = out if isinstance(out, SolveResult) else out[0]
-        jax.block_until_ready(res)
+        with obs_trace.get_tracer().span(self._entry, cat="engine"):
+            if reg is None:
+                return self._fn(oracle, Xt, y, cfg, *args, **kwargs)
+            t0 = time.perf_counter()
+            out = self._fn(oracle, Xt, y, cfg, *args, **kwargs)
+            # solve returns a bare SolveResult; the history/batched entries
+            # return (SolveResult, extra) — and SolveResult is itself a tuple
+            res = out if isinstance(out, SolveResult) else out[0]
+            jax.block_until_ready(res)
         _observe_solve(reg, self._entry, cfg, res, time.perf_counter() - t0)
         return out
 
@@ -926,5 +947,6 @@ class _MetricsEntry:
 
 
 solve = _MetricsEntry(solve, "solve")
-solve_with_history = _MetricsEntry(solve_with_history, "solve_with_history")
+solve_with_history = _MetricsEntry(solve_with_history, "solve_with_history",
+                                   static=("oracle", "cfg", "n_iters"))
 solve_batched = _MetricsEntry(solve_batched, "solve_batched")

@@ -65,6 +65,10 @@ class PathResult(NamedTuple):
     # lane-iterations pruned by the batched driver's per-lane early exit
     # (0 for the sequential drivers)
     saved_iters: int = 0
+    # turns of the batched engine loop over the chunks this call ran: per
+    # chunk, ceil(max lane iterations / effective_fuse_steps) (0 for the
+    # sequential drivers)
+    loop_turns: int = 0
 
     @property
     def mean_active(self) -> float:
@@ -236,6 +240,39 @@ def clear_batched_solver_cache() -> None:
     engine.solve_batched.clear_cache()
 
 
+def _observe_chunk(reg, cfg: FWConfig, real_lanes: int, conv, saved: int,
+                   seconds: float) -> None:
+    """One batched lane chunk into the metrics plane."""
+    lbl = dict(backend=cfg.backend)
+    reg.counter(
+        "fw_lanes_admitted",
+        "delta lanes admitted to batched path chunks",
+        ("backend",),
+    ).inc(real_lanes, **lbl)
+    reg.counter(
+        "fw_lane_freezes",
+        "lanes frozen by per-lane early exit (converged before "
+        "the chunk's while_loop drained)",
+        ("backend",),
+    ).inc(int(conv.sum()), **lbl)
+    reg.counter(
+        "fw_lane_saved_iterations",
+        "lane-iterations pruned vs running every lane to the "
+        "slowest lane's stop",
+        ("backend",),
+    ).inc(saved, **lbl)
+    reg.histogram(
+        "fw_path_chunk_seconds",
+        "wall time per batched lane-chunk dispatch",
+        ("backend",),
+    ).observe(seconds, **lbl)
+    # one latency sample per REAL grid point (amortized over the chunk
+    # dispatch) — sample counts line up with the sequential driver's, so
+    # the two are comparable
+    for _ in range(real_lanes):
+        _observe_point(reg, "batched", cfg, seconds / real_lanes)
+
+
 def fw_path_batched(
     Xt,
     y,
@@ -294,7 +331,7 @@ def fw_path_batched(
                 points[i] = pt
     tracer = obs_trace.get_tracer()
     reg = obs_metrics.get_registry()
-    lanes_mon = obs_monitor.LaneProgressMonitor(max_iters=base_cfg.max_iters)
+    loop_turns = 0
     t_total = time.perf_counter()
     total_dots = sum(pt.n_dots for pt in points if pt is not None)
     total_iters = sum(pt.iterations for pt in points if pt is not None)
@@ -304,86 +341,67 @@ def fw_path_batched(
         for c in range(start_chunk, n_chunks):
             _faults.check_kill("path_chunk", c)
             chunk = padded[c * lane_width : (c + 1) * lane_width]
-            d_arr = jnp.asarray(chunk, Xt.dtype)
-            l1 = jnp.sum(jnp.abs(carry))
-            # per-lane rescaling warm start; carry == 0 (first chunk) stays 0
-            alpha0s = carry[None, :] * (d_arr / jnp.maximum(l1, 1e-12))[:, None]
-            key, *subs = jax.random.split(key, lane_width + 1)
-            lanes_mon.begin_chunk()
-            t0 = time.perf_counter()
-            with tracer.span("fw_path_batched/chunk", cat="path", chunk=c):
-                res, _ = solve_batched_fn(
-                    oracle, Xt, y, base_cfg, jnp.stack(subs), alpha0s, d_arr
-                )
-                res.alpha.block_until_ready()
-            dt = time.perf_counter() - t0
-            carry = res.alpha[-1]
-            alphas = np.asarray(res.alpha)
             real_lanes = min(lane_width, n - c * lane_width)  # ragged final chunk
-            # pruning win for the REAL lanes only: iterations each was spared
-            # while the chunk's while_loop kept running for slower lanes (the
-            # engine's own count would also include the phantom padded lanes)
-            iters = np.asarray(res.iterations)
-            chunk_saved = int(np.sum(iters.max() - iters[:real_lanes]))
-            total_saved += chunk_saved
-            conv = np.asarray(res.converged)[:real_lanes]
-            lanes_mon.end_chunk(
-                c, chunk[:real_lanes], iters[:real_lanes], chunk_saved, conv
-            )
-            if reg is not None:
-                lbl = dict(backend=base_cfg.backend)
-                reg.counter(
-                    "fw_lanes_admitted",
-                    "delta lanes admitted to batched path chunks",
-                    ("backend",),
-                ).inc(real_lanes, **lbl)
-                reg.counter(
-                    "fw_lane_freezes",
-                    "lanes frozen by per-lane early exit (converged before "
-                    "the chunk's while_loop drained)",
-                    ("backend",),
-                ).inc(int(conv.sum()), **lbl)
-                reg.counter(
-                    "fw_lane_saved_iterations",
-                    "lane-iterations pruned vs running every lane to the "
-                    "slowest lane's stop",
-                    ("backend",),
-                ).inc(chunk_saved, **lbl)
-                reg.histogram(
-                    "fw_path_chunk_seconds",
-                    "wall time per batched lane-chunk dispatch",
-                    ("backend",),
-                ).observe(dt, **lbl)
-                # one latency sample per REAL grid point (amortized over
-                # the chunk dispatch) — sample counts line up with the
-                # sequential driver's, so the two are comparable
-                for _ in range(real_lanes):
-                    _observe_point(reg, "batched", base_cfg, dt / real_lanes)
-            for i in range(real_lanes):
-                g = c * lane_width + i
-                idx, val = _sparsify(alphas[i])
-                points[g] = PathPoint(
-                    reg=float(chunk[i]),
-                    objective=float(res.objective[i]),
-                    l1=float(np.sum(np.abs(alphas[i]))),
-                    active=int(res.active[i]),
-                    iterations=int(res.iterations[i]),
-                    n_dots=int(res.n_dots[i]),
-                    seconds=dt / real_lanes,
-                    alpha_nnz_idx=idx,
-                    alpha_nnz_val=val,
-                    gap=_point_gap(res.gap, i),
-                )
-                total_dots += int(res.n_dots[i])
-                total_iters += int(res.iterations[i])
-            if checkpoint_dir is not None and (
-                (c + 1) % checkpoint_every == 0 or c == n_chunks - 1
-            ):
-                n_done = min((c + 1) * lane_width, n)
-                path_ckpt.save_path_checkpoint(
-                    checkpoint_dir, c + 1, key, carry, points[:n_done],
-                    saved_iters=total_saved,
-                )
+            with tracer.span("fw_path_batched/chunk", cat="path", chunk=c):
+                with tracer.span("fw_path_batched/warm_start", cat="path"):
+                    d_arr = jnp.asarray(chunk, Xt.dtype)
+                    l1 = jnp.sum(jnp.abs(carry))
+                    # per-lane rescaling warm start; carry == 0 (first chunk)
+                    # stays 0
+                    scale = d_arr / jnp.maximum(l1, 1e-12)
+                    alpha0s = carry[None, :] * scale[:, None]
+                    key, *subs = jax.random.split(key, lane_width + 1)
+                t0 = time.perf_counter()
+                with tracer.span("fw_path_batched/solve", cat="path"):
+                    res, _ = solve_batched_fn(
+                        oracle, Xt, y, base_cfg, jnp.stack(subs), alpha0s, d_arr
+                    )
+                    res.alpha.block_until_ready()
+                dt = time.perf_counter() - t0
+                with tracer.span("fw_path_batched/collect", cat="path"):
+                    carry = res.alpha[-1]
+                    alphas = np.asarray(res.alpha)
+                    # pruning win for the REAL lanes only: iterations each was
+                    # spared while the chunk's while_loop kept running for
+                    # slower lanes (the engine's own count would also include
+                    # the phantom padded lanes)
+                    iters = np.asarray(res.iterations)
+                    chunk_saved = int(np.sum(iters.max() - iters[:real_lanes]))
+                    total_saved += chunk_saved
+                    # every lane, padded ones included, keeps the loop turning
+                    fuse = (1 if res.effective_fuse_steps is None
+                            else int(np.asarray(res.effective_fuse_steps)))
+                    loop_turns += -(-int(iters.max()) // fuse)
+                    conv = np.asarray(res.converged)[:real_lanes]
+                    if reg is not None:
+                        _observe_chunk(reg, base_cfg, real_lanes, conv,
+                                       chunk_saved, dt)
+                    for i in range(real_lanes):
+                        g = c * lane_width + i
+                        idx, val = _sparsify(alphas[i])
+                        points[g] = PathPoint(
+                            reg=float(chunk[i]),
+                            objective=float(res.objective[i]),
+                            l1=float(np.sum(np.abs(alphas[i]))),
+                            active=int(res.active[i]),
+                            iterations=int(res.iterations[i]),
+                            n_dots=int(res.n_dots[i]),
+                            seconds=dt / real_lanes,
+                            alpha_nnz_idx=idx,
+                            alpha_nnz_val=val,
+                            gap=_point_gap(res.gap, i),
+                        )
+                        total_dots += int(res.n_dots[i])
+                        total_iters += int(res.iterations[i])
+                if checkpoint_dir is not None and (
+                    (c + 1) % checkpoint_every == 0 or c == n_chunks - 1
+                ):
+                    with tracer.span("fw_path_batched/checkpoint", cat="path"):
+                        n_done = min((c + 1) * lane_width, n)
+                        path_ckpt.save_path_checkpoint(
+                            checkpoint_dir, c + 1, key, carry, points[:n_done],
+                            saved_iters=total_saved,
+                        )
     _finish_path(reg, tracer)
     return PathResult(
         points,
@@ -391,6 +409,7 @@ def fw_path_batched(
         total_dots,
         total_iters,
         saved_iters=total_saved,
+        loop_turns=loop_turns,
     )
 
 

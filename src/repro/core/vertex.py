@@ -47,6 +47,7 @@ from repro.kernels.padding import pad_rows as _pad_features
 from repro.kernels.residual_update.residual_update import (
     residual_update as _residual_update_kernel,
 )
+from repro.obs import phases as obs_phases
 from repro.sparse import ops as sparse_ops
 from repro.sparse.matrix import SparseBlockMatrix
 
@@ -119,6 +120,7 @@ def check_matrix_backend(Xt, cfg: FWConfig) -> None:
         )
 
 
+@obs_phases.scoped("fw/init")
 def pad_backend_matrix(Xt, cfg: FWConfig):
     """Zero-pad trailing feature rows for the dense kernel grids — once per
     solve, OUTSIDE the hot loop (DESIGN.md §Padding): to whole blocks for

@@ -1,14 +1,16 @@
 """Solver observability layer (DESIGN.md §Observability).
 
-Three parts, all off the hot path by default:
+Its parts, all off the hot path by default:
   * ``obs.telemetry`` — device-side per-iteration metric rings, carried
     in ``EngineState`` when ``FWConfig.telemetry`` is set (telemetry off
     keeps the default jaxpr bit-identical);
   * ``obs.trace`` — host-side nested span tracing emitting
     Chrome/Perfetto ``trace_event`` JSON plus an aggregate counter
     table;
-  * ``obs.monitor`` / ``obs.report`` — EWMA straggler + lane-progress
-    monitoring and markdown/JSON run-report rendering (CLI:
+  * ``obs.phases`` — the engine's device phases (``jax.named_scope``
+    names) and the map from a compiled program's ops to them;
+  * ``obs.monitor`` / ``obs.report`` — EWMA straggler monitoring and
+    markdown/JSON run-report rendering (CLI:
     ``scripts/solver_report.py``);
   * ``obs.metrics`` / ``obs.export`` — the aggregated metrics plane:
     a labeled Counter/Gauge/Histogram registry the raw primitives
@@ -38,7 +40,7 @@ from repro.obs.metrics import (
     tracer_to_registry,
     use_registry,
 )
-from repro.obs.monitor import LaneProgressMonitor, StepMonitor
+from repro.obs.monitor import StepMonitor
 from repro.obs.report import build_report, render_markdown, write_report
 from repro.obs.telemetry import (
     EVENT_AWAY,
@@ -65,7 +67,7 @@ from repro.obs.trace import (
 __all__ = [
     "Counter", "EVENT_AWAY", "EVENT_DROP", "EVENT_FW", "EVENT_LAZY_HIT",
     "EVENT_NAMES", "EVENT_PAIRWISE", "EVENT_PARTAN", "Gauge", "Histogram",
-    "LaneProgressMonitor", "MetricsRegistry", "MetricsServer", "StepMonitor",
+    "MetricsRegistry", "MetricsServer", "StepMonitor",
     "Tracer", "TelemetryRing", "TelemetrySpec", "build_report", "get_registry",
     "get_tracer", "install_registry", "install_ring_sink", "register_sink",
     "render_markdown", "render_openmetrics", "ring_batch_to_registry",

@@ -2,7 +2,7 @@
 
 A ``MetricsRegistry`` is the host-side aggregation layer that the raw
 observability primitives — ``TelemetryRing`` flushes, ``Tracer``
-spans/counters, ``StepMonitor``/``LaneProgressMonitor`` — feed, and that
+spans/counters, ``StepMonitor`` — feed, and that
 the export layer (``repro.obs.export``: OpenMetrics text, JSON
 snapshots, the background ``/metrics`` HTTP endpoint) serves. Three
 metric kinds, all labeled:

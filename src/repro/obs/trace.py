@@ -6,18 +6,27 @@ aggregate counter table, and emits them in the Chrome/Perfetto
 with ``ph: "X"`` complete events), so a solver run can be dropped
 straight into https://ui.perfetto.dev or chrome://tracing.
 
-Placement contract: spans measure HOST-side phases (path-driver grid
-points, shard IO, distributed-solver dispatch, eager colstats). A span
+Placement contract: spans measure HOST-side phases (path-driver chunks
+and their warm start / solve / collect / checkpoint, engine dispatches,
+shard IO, distributed-solver dispatch). Every ``span`` also enters
+``jax.profiler.TraceAnnotation(name)``, so while a ``jax.profiler``
+trace is recording, the span lands in the XSpace's host plane on the
+profiler's own clock, beside the device's ops: that is the one host
+sink a profile reads (``bench/`` reduces it). The span's Chrome event
+keeps this tracer's timebase (microseconds since construction). A span
 opened inside a jitted function measures trace time, not run time —
-the device-side per-iteration story lives in the telemetry ring
-(``repro.obs.telemetry``), not here. Counters recorded at trace time
-(e.g. the per-collective counters in ``distributed/backend``) count
-ops PER COMPILED PROGRAM; multiply by iterations for run totals.
+device-side phases are named by ``jax.named_scope`` (``obs.phases``)
+and the per-iteration story lives in the telemetry ring
+(``repro.obs.telemetry``). Counters recorded at trace time (e.g. the
+per-collective counters in ``distributed/backend``) count ops PER
+COMPILED PROGRAM; multiply by iterations for run totals.
 
 There is always an active tracer: ``get_tracer()`` returns the tracer
 installed by the innermost ``use_tracer(...)`` context, falling back to
 a process-global default, so instrumentation points never need a
-None-check and ``utils.timing.timed`` always has a sink.
+None-check and ``utils.timing.timed`` always has a sink. The default
+keeps no events (its spans reach the profiler only, and its counter
+table aggregates); install a ``Tracer`` to keep a Chrome trace.
 """
 from __future__ import annotations
 
@@ -29,15 +38,20 @@ import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
+from jax import profiler as _profiler
+
 _VALID_PH = {"B", "E", "X", "i", "I", "C", "M", "b", "e", "n", "s", "t", "f"}
 
 
 class Tracer:
     """Collects trace events; thread-safe appends, one timebase per
-    instance (microseconds since construction)."""
+    instance (microseconds since construction). With
+    ``keep_events=False`` it records no event (spans still reach the
+    profiler; counters still aggregate)."""
 
-    def __init__(self, name: str = "repro"):
+    def __init__(self, name: str = "repro", keep_events: bool = True):
         self.name = name
+        self.keep_events = keep_events
         self.events: List[dict] = []
         self.counters: Dict[str, float] = {}
         self._lock = threading.Lock()
@@ -50,8 +64,9 @@ class Tracer:
 
     # -- low-level ---------------------------------------------------------
     def _add(self, ev: dict) -> None:
-        with self._lock:
-            self.events.append(ev)
+        if self.keep_events:
+            with self._lock:
+                self.events.append(ev)
 
     def _now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
@@ -62,10 +77,12 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     @contextmanager
     def span(self, name: str, cat: str = "solver", **args: Any):
-        """Nested wall-clock span -> one ``ph: "X"`` complete event."""
+        """Nested wall-clock span -> one ``ph: "X"`` complete event, and
+        a ``TraceAnnotation`` on the profiler's clock."""
         ts = self._now_us()
         try:
-            yield self
+            with _profiler.TraceAnnotation(name):
+                yield self
         finally:
             self._add(
                 {"name": name, "cat": cat, "ph": "X", "ts": ts,
@@ -96,6 +113,8 @@ class Tracer:
         with self._lock:
             total = self.counters.get(name, 0.0) + value
             self.counters[name] = total
+            if not self.keep_events:
+                return
             self.events.append(
                 {"name": name, "cat": cat, "ph": "C", "ts": self._now_us(),
                  "pid": self._pid, "tid": 0, "args": {"value": total}}
@@ -146,7 +165,7 @@ class Tracer:
 # Active-tracer plumbing
 # --------------------------------------------------------------------------
 
-_default_tracer = Tracer("repro-default")
+_default_tracer = Tracer("repro-default", keep_events=False)
 _stack: List[Tracer] = []
 _stack_lock = threading.Lock()
 

@@ -31,7 +31,6 @@ from repro.obs import (
     EVENT_LAZY_HIT,
     EVENT_PAIRWISE,
     EVENT_PARTAN,
-    LaneProgressMonitor,
     StepMonitor,
     TelemetrySpec,
     Tracer,
@@ -556,22 +555,6 @@ class TestMonitors:
         # the one import path is repro.obs.monitor
         with pytest.raises(ImportError):
             importlib.import_module("repro.runtime.monitor")
-
-    def test_lane_progress_monitor(self):
-        times = iter([0.0, 1.0, 2.0, 3.0])
-        mon = LaneProgressMonitor(
-            max_iters=100, chunk_monitor=StepMonitor(clock=lambda: next(times))
-        )
-        tr = Tracer("t")
-        with use_tracer(tr):
-            mon.begin_chunk()
-            rec = mon.end_chunk(0, [1.0, 2.0], [30, 50], 20, [True, True])
-        assert rec["lane_saved"] == [20, 0]
-        assert rec["freeze_at"] == [30, None]
-        s = mon.summary()
-        assert s["saved_iters"] == 20 and s["frozen_lanes"] == 1
-        assert tr.counter_table()["path/saved_iters"] == 20.0
-        assert validate_chrome_trace(tr.to_chrome()) == []
 
 
 class TestReport:
