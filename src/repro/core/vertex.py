@@ -500,13 +500,19 @@ def matvec(Xt, beta: jax.Array, cfg: Optional[FWConfig] = None) -> jax.Array:
 def grad_full(Xt, w: jax.Array, cfg: Optional[FWConfig] = None) -> jax.Array:
     """Full LINEAR gradient -X^T w over every feature — the O(nnz)/O(p*m)
     certification pass behind the oracle ``gap()`` protocol, never the hot
-    loop. Distributed: local features psum over "data", all_gather over
-    "model" — replicated on every shard. May return backend-padded length;
-    callers slice [:p]."""
+    loop. Sparse: the Pallas ``fw_sparse_xtw`` kernel where
+    ``use_sparse_kernel(cfg)`` holds (TPU by default), else the XLA
+    gather (also without ``cfg``). Distributed: local features psum over
+    "data", all_gather over "model" — replicated on every shard. May
+    return backend-padded length; callers slice [:p]."""
     if dist_spec(cfg) is not None:
         from repro.distributed import backend as dist_backend
 
         return dist_backend.dist_grad_full(Xt, w, cfg)
     if isinstance(Xt, SparseBlockMatrix):
-        return -sparse_ops.sparse_transpose_matvec(Xt, w)
+        if cfg is None:
+            return -sparse_ops.sparse_transpose_matvec(Xt, w)
+        return -sparse_ops.sparse_transpose_matvec(
+            Xt, w, use_kernel=use_sparse_kernel(cfg), interpret=use_interpret(cfg)
+        )
     return -(Xt @ w)

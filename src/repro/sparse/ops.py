@@ -20,7 +20,10 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.sparse_colstats.sparse_colstats import sparse_colstats_fused
+from repro.kernels.sparse_colstats.sparse_colstats import (
+    sparse_colstats_fused,
+    sparse_xtw,
+)
 from repro.kernels.sparse_grad.ref import sparse_sampled_scores_ref
 from repro.kernels.sparse_grad.sparse_grad import sparse_sampled_scores
 from repro.sparse.matrix import SparseBlockMatrix
@@ -213,9 +216,26 @@ def sparse_matvec(mat: SparseBlockMatrix, beta: jax.Array) -> jax.Array:
     return out.astype(beta.dtype)
 
 
-def sparse_transpose_matvec(mat: SparseBlockMatrix, r: jax.Array) -> jax.Array:
+def sparse_transpose_matvec(
+    mat: SparseBlockMatrix,
+    r: jax.Array,
+    *,
+    use_kernel: bool = False,
+    interpret: bool = False,
+) -> jax.Array:
     """Xt @ r over ALL features — O(total nnz). Certification/grids only
-    (duality_gap, lambda_grid); the hot loop never calls this."""
+    (the certified gap, duality_gap, lambda_grid); the hot loop never
+    calls this.
+
+    With ``use_kernel`` the Pallas twin of the column-statistics kernel
+    (``fw_sparse_xtw``, ``kernels/sparse_colstats``) makes the one pass
+    over the ELL bricks; otherwise an XLA gather and reduce, the CPU and
+    distributed path. Accumulates in f32 and returns length p in the
+    storage dtype.
+    """
+    if use_kernel:
+        out = sparse_xtw(mat.values, mat.rows, r, interpret=interpret)
+        return out[: mat.p].astype(mat.dtype)
     vals = mat.values.astype(jnp.float32)
     gathered = jnp.take(r.astype(jnp.float32), mat.rows, axis=0)
     return jnp.sum(vals * gathered, axis=2).reshape(-1)[: mat.p].astype(mat.dtype)

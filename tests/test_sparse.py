@@ -269,6 +269,90 @@ class TestLaneGather:
         )
 
 
+class TestSparseXtw:
+    """``fw_sparse_xtw`` (interpret mode), the X^T w of the certified gap,
+    against the XLA gather: p not a multiple of the block size, m not a
+    multiple of 128, padded ELL slots, both storage dtypes."""
+
+    @pytest.mark.parametrize("p,m,bs,density,dtype,tol", [
+        (300, 80, 128, 0.05, np.float32, 1e-6),
+        (777, 300, 256, 0.05, np.float32, 1e-6),
+        (130, 700, 32, 0.3, np.float32, 1e-6),
+        (300, 200, 128, 0.1, jnp.bfloat16, 1e-2),
+    ])
+    def test_kernel_matches_xla(self, p, m, bs, density, dtype, tol):
+        _, mat, w = _sparse_dense_pair(p, m, density, seed=p + m, block_size=bs)
+        mat = mat.astype(dtype)
+        w = jnp.asarray(w).astype(dtype)
+        counts = (np.asarray(mat.values, np.float32) != 0).sum(axis=2)
+        assert counts.min() < mat.nnz_max  # some slots are padding
+        got = sops.sparse_transpose_matvec(mat, w, use_kernel=True, interpret=True)
+        want = sops.sparse_transpose_matvec(mat, w)
+        assert got.shape == (p,) and got.dtype == want.dtype == mat.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol * 10)
+
+    def test_padded_features_are_zero(self):
+        from repro.kernels.sparse_colstats.sparse_colstats import sparse_xtw
+
+        _, mat, w = _sparse_dense_pair(300, 80, 0.05, seed=5)
+        out = sparse_xtw(mat.values, mat.rows, jnp.asarray(w), interpret=True)
+        assert out.shape == (mat.p_padded,) and out.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(out[mat.p:]), 0.0)
+
+    @pytest.mark.parametrize("entry", ["solve", "solve_batched"])
+    def test_certified_gap_kernel_matches_xla(self, sparse_problem, rng_key, entry):
+        """The gap a solve reports (one per lane in the batched entry) is
+        the same with the kernel as with the XLA gather, and only the
+        kernel configuration runs ``fw_sparse_xtw``."""
+        from repro.core import engine
+        from repro.core.fw_lasso import LASSO
+
+        _, mat, y = sparse_problem
+        deltas = jnp.asarray([10.0, 40.0, DELTA], jnp.float32)
+        base = dict(delta=DELTA, backend="sparse", sampling="uniform", kappa=60,
+                    max_iters=400, tol=1e-6, report_gap=True, interpret=True)
+        if entry == "solve":
+            args = (rng_key, None, DELTA)
+        else:
+            args = (jax.random.split(rng_key, deltas.shape[0]),
+                    jnp.zeros((deltas.shape[0], mat.p), jnp.float32), deltas)
+        fn = getattr(engine, entry)
+        gaps = {}
+        for kernel in (False, True):
+            cfg = FWConfig(sparse_kernel=kernel, **base)
+            jaxpr = str(jax.make_jaxpr(
+                lambda: fn.__wrapped__(LASSO, mat, y, cfg, *args))())
+            assert ("fw_sparse_xtw" in jaxpr) == kernel
+            res = fn(LASSO, mat, y, cfg, *args)
+            gaps[kernel] = np.asarray((res if entry == "solve" else res[0]).gap)
+        assert gaps[True].shape == ((3,) if entry == "solve_batched" else ())
+        assert np.all(gaps[False] > 0)
+        np.testing.assert_allclose(gaps[True], gaps[False], rtol=1e-6)
+
+    @pytest.mark.parametrize("family", ["lasso", "elastic_net", "logistic"])
+    def test_oracle_gap_kernel_matches_xla(self, sparse_problem, rng_key, family):
+        from repro.core.fw_elasticnet import ENOracle
+        from repro.core.fw_lasso import LASSO
+        from repro.core.fw_logistic import LOGISTIC
+
+        _, mat, y = sparse_problem
+        oracle = {"lasso": LASSO, "elastic_net": ENOracle(l2=0.5),
+                  "logistic": LOGISTIC}[family]
+        if family == "logistic":
+            y = jnp.where(y >= 0, 1.0, -1.0).astype(jnp.float32)
+        alpha = jax.random.normal(rng_key, (mat.p,), jnp.float32)
+        alpha = alpha * (jax.random.uniform(rng_key, (mat.p,)) < 0.1)
+        base = dict(delta=DELTA, backend="sparse", interpret=True)
+        got = float(oracle.gap(mat, y, alpha, DELTA,
+                               FWConfig(sparse_kernel=True, **base)))
+        want = float(oracle.gap(mat, y, alpha, DELTA,
+                                FWConfig(sparse_kernel=False, **base)))
+        assert want > 0
+        assert got == pytest.approx(want, rel=1e-6)
+
+
 class TestSolverParity:
     """fw_solve(backend='sparse') == fw_solve(backend='xla') end to end on
     the SAME (sparsified) problem. p=300 is not block-divisible, so the

@@ -24,7 +24,10 @@ from repro.kernels.colstats.colstats import colstats
 from repro.kernels.fused_step import fused_step
 from repro.kernels.fw_grad.fw_grad import row_scores, sampled_scores
 from repro.kernels.residual_update.residual_update import residual_update
-from repro.kernels.sparse_colstats.sparse_colstats import sparse_colstats_fused
+from repro.kernels.sparse_colstats.sparse_colstats import (
+    sparse_colstats_fused,
+    sparse_xtw,
+)
 from repro.kernels.sparse_grad.sparse_grad import sparse_sampled_scores
 from repro.sparse.matrix import SparseBlockMatrix
 
@@ -102,6 +105,12 @@ class TestKernels:
         hlo = _compile(lambda v, r, y: sparse_colstats_fused(v, r, y),
                        vals, rows, chip((M_E2006,)))
         assert _has_kernel(hlo)
+
+    def test_sparse_xtw(self, chip):
+        vals, rows = _e2006(chip)
+        hlo = _compile(lambda v, r, w: sparse_xtw(v, r, w),
+                       vals, rows, chip((M_E2006,)))
+        assert _has_kernel(hlo) and "fw_sparse_xtw" in hlo
 
     def test_colstats(self, chip):
         hlo = _compile(lambda X, y: colstats(X, y),
