@@ -1,24 +1,28 @@
 """Whether what the timed path produced is correct.
 
 Every answer of the window (each grid point of each path, or each fit)
-is evaluated by the plain reference (``bench/reference.py``) from its
-returned coefficients and the benchmark's own data, and judged by four
-numbers, each against its limit from ``bench/limits/<cell>.json``:
+is evaluated by the plain reference that the configuration names
+(``reference``: ``bench/reference.py`` for the lasso,
+``bench/reference_logistic.py`` for l1-logistic) from its returned
+coefficients and the benchmark's own data, and judged by four numbers,
+each against its limit from ``bench/limits/<cell>.json``:
 
     shortfall  (f(0) - (f_ref - g_ref)) / (f(0) - f(alpha)): how many times
-               the decrease from f(0) = ||y||^2 / 2 that the reference
-               certifies at the same radius (f_ref, g_ref: the reference
-               solver's objective and certified gap, solved from zero)
-               exceeds the answer's own decrease; infinite where the
-               answer does not go below f(0), as an engine that never
-               moves from alpha = 0
+               the decrease from the problem's f(0) (the reference's
+               ``f_zero``: ||y||^2 / 2 for the lasso, log 2 a label for
+               l1-logistic) that the reference certifies at the same
+               radius (f_ref, g_ref: the reference solver's objective and
+               certified gap, solved from zero) exceeds the answer's own
+               decrease; infinite where the answer does not go below
+               f(0), as an engine that never moves from alpha = 0
     obj_err    |reported objective - f(alpha)| / f(0)
     gap_err    |reported gap - g(alpha)| / f(0), g the certified Frank-Wolfe
                gap of the returned coefficients
     l1_excess  max(0, ||alpha||_1 / delta - 1)
 
-The errors are shares of f(0), the problem's own scale: where the radius
-lets the design fit y (triazines' m = 186), f(alpha) itself goes to ~0.
+The errors are shares of the problem's f(0), its own scale: where the
+radius lets the design fit y (triazines' m = 186), f(alpha) itself goes
+to ~0.
 ``shortfall`` is read on the answers at a sample of the radii, drawn from
 the run's seed: every radius where there are at most ``REF_POINTS``, else
 ``REF_POINTS - 1`` drawn ones and the largest (the slowest to solve). The
@@ -35,8 +39,6 @@ import math
 
 import numpy as np
 
-from bench import reference
-
 NUMBERS = ("shortfall", "obj_err", "gap_err", "l1_excess")
 REF_POINTS = 8
 
@@ -51,18 +53,19 @@ def reference_radii(answers: list, seed: int) -> np.ndarray:
     return np.sort(np.append(radii[pick], radii[-1]))
 
 
-def readings(data: dict, answers: list, seed: int) -> dict:
+def readings(data: dict, answers: list, seed: int, reference) -> dict:
     """Per-answer numbers (arrays over answers; ``shortfall`` is -inf on
     the answers off the reference's radii) and, under ``ref``, the
     reference's own readings: the number of radii, its largest gap over
-    f(0), and its smallest certified decrease over f(0)."""
+    f(0), and its smallest certified decrease over f(0). ``reference`` is
+    the configuration's plain reference module."""
     supports = [(np.asarray(a["idx"], np.int32), np.asarray(a["val"], np.float32))
                 for a in answers]
     deltas = np.array([a["delta"] for a in answers], np.float64)
     f, l1, gap = reference.evaluate(data, supports, deltas)
     radii = reference_radii(answers, seed)
     f_ref, g_ref = reference.optimum(data, radii)
-    f_zero = 0.5 * float(np.sum(np.square(np.asarray(data["y"], np.float64))))
+    f_zero = reference.f_zero(data)
     ref_decrease = f_zero - (f_ref - g_ref)
     at = {float(d): i for i, d in enumerate(radii)}
     shortfall = np.full(len(answers), -np.inf)
@@ -98,11 +101,13 @@ def grid_errors(answers: list, grid) -> int:
     return bad
 
 
-def judge(data: dict, answers: list, limits: dict, seed: int, grid=None) -> dict:
+def judge(data: dict, answers: list, limits: dict, seed: int, reference,
+          grid=None) -> dict:
     """{"correct", "attempted", "failed", "numbers": {name: (value, limit)},
-    "ref": the reference's own readings}."""
+    "ref": the reference's own readings}, judged with ``reference``, the
+    configuration's plain reference module."""
     if answers:
-        r = readings(data, answers, seed)
+        r = readings(data, answers, seed, reference)
     else:
         r = {k: np.zeros(0) for k in NUMBERS}
         r["ref"] = {}

@@ -3,9 +3,8 @@ the program's entry point with them.
 
 A traffic file (``bench/traffic/<name>.json``) states what a user states:
 
-    entry           "path": ``repro.core.path.fw_path_batched`` over a
-                    delta grid; "fit": ``repro.core.fw_solve`` at single
-                    deltas, cold
+    entry           "path": ``fw_path_batched`` over a delta grid; "fit":
+                    ``solve`` at single deltas, cold
     kappa           sampling-set size |S|, or
     kappa_fraction  |S| = ceil(kappa_fraction * p)
     points, ratio   the grid: ``points`` deltas, log-spaced from
@@ -21,6 +20,14 @@ may cap the points of its paths at ``path_points``: the grid then keeps
 its span with that many points. Every other ``FWConfig`` field stays
 at the library default, except ``backend="sparse"``, which a block-ELL
 design requires.
+
+The configuration's ``problem`` names the program's oracle
+(``repro.core.LASSO`` or ``repro.core.LOGISTIC``), passed to the entry
+points. On one device they are ``repro.core.path.fw_path_batched`` and
+``repro.core.engine.solve``; where the law has laid the data over a
+(data, model) mesh, ``repro.distributed.fw_path_batched`` and
+``repro.distributed.solve`` on a ``ShardedOperand`` built from the placed
+arrays.
 
 The window runs whole units back to back: a path, or a round of fits
 (one at each delta of the grid, in an order drawn from the seed, so
@@ -57,20 +64,51 @@ def kappa_of(traffic: dict, p: int) -> int:
     return int(math.ceil(float(traffic["kappa_fraction"]) * p))
 
 
+def oracle_of(spec: dict):
+    """The program's oracle for the configuration's ``problem``."""
+    from repro.core import LASSO, LOGISTIC
+
+    return {"lasso": LASSO, "logistic": LOGISTIC}[spec.get("problem", "lasso")]
+
+
+def on_mesh(data: dict) -> bool:
+    """Whether the law laid the data over a (data, model) mesh: block-ELL
+    arrays of shape (data, model blocks, block_size, nnz_max)."""
+    return data["kind"] == "block_ell" and data["values"].ndim == 4
+
+
 def program_operands(data: dict, dtype=jnp.float32):
     """The program's design matrix and targets for the benchmark's data,
-    in ``dtype`` (the control runs the program in bfloat16)."""
+    in ``dtype`` (the control runs the program in bfloat16). Data laid
+    over a mesh becomes one ``ShardedOperand``, built from the placed
+    arrays as they lie; it holds the targets too."""
     from repro.sparse.matrix import SparseBlockMatrix
 
     y = data["y"].astype(dtype)
     if data["kind"] == "dense":
         return data["xt"].astype(dtype), y
     values = data["values"]
+    if on_mesh(data):
+        op = _sharded(data, values.astype(dtype), y)
+        return op, op.y
     mat = SparseBlockMatrix(
         values=values.astype(dtype), rows=data["rows"], p=data["p"], m=data["m"],
         block_size=values.shape[1], nnz_max=values.shape[2],
     )
     return mat, y
+
+
+def _sharded(data: dict, values, y):
+    from repro.distributed import ShardedOperand, mesh_spec
+
+    mesh = data["values"].sharding.mesh
+    spec = mesh_spec(mesh)
+    return ShardedOperand(
+        mesh=mesh, spec=spec, p=data["p"], m=data["m"],
+        m_local=y.shape[0] // spec.n_data, y=y, values=values, rows=data["rows"],
+        block_size=values.shape[2], nnz_max=values.shape[3],
+        nb_local=values.shape[1] // spec.n_model,
+    )
 
 
 def fw_config(data: dict, traffic: dict):
@@ -104,6 +142,8 @@ class Driver:
             raise ValueError(f"unknown traffic entry {self.entry!r}")
         self.seed = int(seed)
         self.X, self.y = program_operands(data, dtype)
+        self.sharded = on_mesh(data)
+        self.oracle = oracle_of(spec)
         self.cfg = fw_config(data, traffic)
         self.dmax = delta_max(data, spec)
         self.ratio = float(traffic["ratio"])
@@ -121,12 +161,18 @@ class Driver:
         return (self.seed * _SEED_MIX + i) % (2**31 - 1)
 
     def _path(self, i: int, grid, lane_width=None) -> list:
-        from repro.core import path
-
         t0 = time.perf_counter()
-        res = path.fw_path_batched(self.X, self.y, grid, self.cfg,
-                                   seed=self._seed_of(i),
-                                   lane_width=lane_width or self.lane_width)
+        kw = dict(seed=self._seed_of(i), lane_width=lane_width or self.lane_width,
+                  oracle=self.oracle)
+        if self.sharded:
+            from repro import distributed
+
+            res = distributed.fw_path_batched(self.X, grid, self.cfg,
+                                              report_gap=self.cfg.report_gap, **kw)
+        else:
+            from repro.core import path
+
+            res = path.fw_path_batched(self.X, self.y, grid, self.cfg, **kw)
         seconds = time.perf_counter() - t0
         self.path_results.append(res)
         return [
@@ -138,11 +184,16 @@ class Driver:
         ]
 
     def _fit(self, i: int, delta: float) -> dict:
-        from repro.core import fw_solve
-
         t0 = time.perf_counter()
         key = jax.random.PRNGKey(self._seed_of(i))
-        res = fw_solve(self.X, self.y, self.cfg, key, None, delta)
+        if self.sharded:
+            from repro import distributed
+
+            res = distributed.solve(self.oracle, self.X, self.cfg, key, None, delta)
+        else:
+            from repro.core import engine
+
+            res = engine.solve(self.oracle, self.X, self.y, self.cfg, key, None, delta)
         idx, val = _host_pairs(res.alpha)
         objective = float(res.objective)
         gap = float(res.gap) if res.gap is not None else float("nan")
@@ -189,5 +240,6 @@ class Driver:
                 return elapsed
 
     def release(self) -> None:
-        """Drop the program's operands (its device buffers)."""
+        """Drop the program's operands (its device buffers; a sharded
+        operand holds its targets)."""
         self.X = self.y = None

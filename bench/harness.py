@@ -2,7 +2,13 @@
 
     configuration   the file its ``configs`` entry names (a JSON object of
                     sizes, with ``law``: the generator in
-                    ``bench/laws/<law>.py``)
+                    ``bench/laws/<law>.py``, and ``reference``: the file of
+                    its plain reference). Optional keys: ``problem``,
+                    "lasso" (the default) or "logistic", the problem its
+                    targets pose and its program solves; ``mesh``,
+                    ``{"data": a, "model": b}``, the layout of its data
+                    over the cell's chips (a * b of them), which the law
+                    draws the data onto
     traffic         ``bench/traffic/<traffic>.json``
     limits          ``bench/limits/<workload>.json``: the limit of each
                     number that decides ``correct``
@@ -14,6 +20,7 @@ adding files and entries; nothing here names one of them.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -21,6 +28,11 @@ from types import ModuleType
 from typing import NamedTuple
 
 BENCH_DIR = "bench"
+PROBLEMS = ("lasso", "logistic")
+
+
+class CellError(ValueError):
+    """A cell whose files do not fit together: nothing is measured."""
 
 
 class Cell(NamedTuple):
@@ -58,6 +70,14 @@ def find_cell(root: Path, workload: str) -> Cell:
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     spec = json.loads((root / configs[w["config"]]["file"]).read_text())
+    problem = spec.get("problem", "lasso")
+    if problem not in PROBLEMS:
+        raise CellError(f"{w['config']}: unknown problem {problem!r}; known: {PROBLEMS}")
+    if "mesh" in spec:
+        a, b = int(spec["mesh"]["data"]), int(spec["mesh"]["model"])
+        if a * b != int(w["chips"]):
+            raise CellError(f"{workload}: its configuration's mesh {a} x {b} is not "
+                            f"the cell's {w['chips']} chips")
     traffic = json.loads((root / BENCH_DIR / "traffic" / f"{w['traffic']}.json").read_text())
     limits = json.loads((root / BENCH_DIR / "limits" / f"{workload}.json").read_text())["limits"]
     e2e = [m for m in bench["end_to_end"]
@@ -69,6 +89,18 @@ def find_cell(root: Path, workload: str) -> Cell:
 
 def law(root: Path, spec: dict) -> ModuleType:
     return load_module(Path(root) / BENCH_DIR / "laws" / f"{spec['law']}.py")
+
+
+def reference(root: Path, spec: dict) -> ModuleType:
+    """The plain reference the configuration names: ``evaluate``,
+    ``optimum`` and ``f_zero`` of its problem (loaded once a process, so
+    that its compiled programs serve every run)."""
+    return _load_once((Path(root) / spec["reference"]).resolve())
+
+
+@functools.lru_cache(maxsize=None)
+def _load_once(path: Path) -> ModuleType:
+    return load_module(path)
 
 
 def metric_reader(root: Path, name: str) -> ModuleType:

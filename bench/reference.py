@@ -12,7 +12,9 @@ benchmark's own data (the dicts the laws in ``bench/laws`` return); it
 imports nothing of the program. Points are evaluated in batches of
 ``BATCH`` coefficient vectors, and the O(nnz) pass over a block-ELL
 design runs in chunks of feature blocks, so the reference fits beside
-the data on one chip.
+the data on one chip. A block-ELL design laid over a mesh with one
+data shard, (1, NB, block_size, nnz_max), is read as (NB, block_size,
+nnz_max).
 """
 from __future__ import annotations
 
@@ -90,12 +92,27 @@ def _bucket(n: int) -> int:
     return max(64, 1 << int(np.ceil(np.log2(max(n, 1)))))
 
 
+def _one_shard(data: dict) -> dict:
+    """``data`` with a one-data-shard block-ELL layout seen as one design."""
+    if data["kind"] != "block_ell" or data["values"].ndim == 3:
+        return data
+    if data["values"].shape[0] != 1:
+        raise ValueError("the lasso reference reads designs with one data shard")
+    return {**data, "values": data["values"][0], "rows": data["rows"][0]}
+
+
+def f_zero(data: dict) -> float:
+    """f(0) = ||y||^2 / 2, in float64."""
+    return 0.5 * float(np.sum(np.square(np.asarray(data["y"], np.float64))))
+
+
 def evaluate(data: dict, supports, deltas):
     """Objective, l1 norm and certified gap of each coefficient vector.
 
     ``supports`` is a list of (indices, values) host arrays, ``deltas``
     the radius of each. Returns three float64 arrays.
     """
+    data = _one_shard(data)
     deltas = np.asarray(deltas, np.float64)
     objective, l1, gap = [], [], []
     for s0 in range(0, len(supports), BATCH):
@@ -249,6 +266,7 @@ def optimum(data: dict, deltas) -> tuple:
     the program. f_ref - gap_ref is a lower bound on the optimum that
     holds whatever the solver's accuracy. Returns two float64 arrays.
     """
+    data = _one_shard(data)
     deltas = np.asarray(deltas, np.float64)
     nb = max(1, BATCH // 4)
     p, y = data["p"], data["y"]

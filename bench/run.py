@@ -3,19 +3,23 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up makes the cell's data on the device from ``--seed`` (the law its
-configuration file names, ``bench/laws``), then compiles and warms up
+configuration file names, ``bench/laws``; where the configuration states
+a ``mesh``, over a (data, model) mesh of the cell's chips, each chip
+drawing its own share), then compiles and warms up
 every program of the window through one warm-up unit. The window runs
 the cell's traffic (``bench/drive.py``) for ``--seconds``; with
 ``--trace 1`` it runs one path, or fits for a few seconds, under the
 profiler instead, and reports the per-layer metrics. Once the window has
 closed and its device memory is released, every answer of the window is
-judged against the plain reference (``bench/check.py``).
+judged against the plain reference that the configuration names
+(``bench/check.py``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced), with
 ``checks`` last: each number compared, with its limit. The same numbers
-end stderr. With no TPU, fewer chips than the cell asks for, or no
-program beside the benchmark, it prints no result and exits non-zero.
+end stderr. With no TPU, fewer chips than the cell asks for, a mesh that
+is not the cell's chips, or no program beside the benchmark, it prints
+no result and exits non-zero.
 """
 from __future__ import annotations
 
@@ -131,8 +135,17 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
 
     from bench.drive import Driver
 
+    law = harness.law(root, cell.spec)
     t0 = time.perf_counter()
-    data = harness.law(root, cell.spec).generate(cell.spec, seed_key(args.seed))
+    if "mesh" in cell.spec:
+        from repro import distributed
+
+        mesh = distributed.fw_mesh(n_data=int(cell.spec["mesh"]["data"]),
+                                   n_model=int(cell.spec["mesh"]["model"]),
+                                   devices=jax.devices()[: cell.chips])
+        data = law.generate(cell.spec, seed_key(args.seed), mesh=mesh)
+    else:
+        data = law.generate(cell.spec, seed_key(args.seed))
     jax.block_until_ready(data)
     _say(f"data made on the device in {time.perf_counter() - t0:.3f} s")
     driver = Driver(data, cell.spec, cell.traffic, args.seed,
@@ -155,7 +168,8 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
     _say(f"window {elapsed:.3f} s, {driver.units} units, {len(answers)} answers")
 
     t0 = time.perf_counter()
-    verdict = check.judge(data, answers, cell.limits, args.seed, grid)
+    verdict = check.judge(data, answers, cell.limits, args.seed,
+                          harness.reference(root, cell.spec), grid)
     ref_s = time.perf_counter() - t0
     _say(f"reference check {ref_s:.3f} s; reference radii "
          f"{verdict['ref'].get('radii')}, its largest gap over f(0) "
@@ -193,9 +207,11 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
 def main(argv=None) -> int:
     if str(ROOT) not in sys.path:
         sys.path.insert(0, str(ROOT))
+    from bench.harness import CellError
+
     try:
         out = run(argv)
-    except NoDevice as e:
+    except (NoDevice, CellError) as e:
         print(f"bench: {e}; nothing was measured", file=sys.stderr)
         return 1
     for k, c in out["checks"].items():

@@ -61,6 +61,55 @@ def _add_kept(bench: dict, dst: Path) -> None:
             e2e[metric]["workloads"].append(name)
 
 
+# An l1-logistic design laid over a (1, n) mesh (law block_ell_sharded),
+# with a path and a fit traffic of 4 points each, added as new files.
+LOGISTIC_SPEC = {
+    "name": "l1logit", "law": "block_ell_sharded", "problem": "logistic",
+    "reference": "bench/reference_logistic.py", "m": 200, "p": 2000,
+    "block_size": 256, "nnz_max": 16, "col_density": 0.03, "n_relevant": 10,
+    "coef_scale": 2.0, "noise": 0.5, "delta_max_share": 0.5, "dtype": "float32",
+}
+LOGISTIC_TRAFFIC = {
+    "path": {"entry": "path", "kappa": 194, "points": 4, "lane_width": 2,
+             "ratio": 100, "report_gap": True},
+    "fit": {"entry": "fit", "kappa": 194, "points": 4, "ratio": 100,
+            "report_gap": True},
+}
+# CPU readings at this size, four seeds a cell (PERF.md §2): sound runs
+# shortfall <= 1.0028, obj_err <= 1.1e-07, gap_err <= 2.4e-08, l1_excess
+# <= 2.0e-06; answers scaled by 1.001 obj_err >= 5.36e-05, gap_err >=
+# 5.15e-05 (l1_excess 1.0e-03: at its limit, not its number to catch);
+# a frozen step shortfall inf; flipped labels obj_err >= 0.12
+LOGISTIC_LIMITS = {"shortfall": 2.0, "obj_err": 1e-5, "gap_err": 1e-5,
+                   "l1_excess": 1e-3}
+
+
+def add_logistic_cells(root: Path, chips: int) -> list:
+    """Add the l1-logistic cells to the benchmark root at ``root`` as new
+    files and BENCHMARK.json entries, on a (1, chips) mesh; returns the
+    cells' names."""
+    config = f"l1logit-{chips}"
+    spec = dict(LOGISTIC_SPEC, name=config, mesh={"data": 1, "model": chips})
+    (root / "bench" / "configs" / f"{config}.json").write_text(json.dumps(spec))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": config, "source": "x", "reduced": [],
+                             "file": f"bench/configs/{config}.json", "why": "x"})
+    names = []
+    for entry, traffic in LOGISTIC_TRAFFIC.items():
+        name = f"{config}.{entry}"
+        (root / "bench" / "traffic" / f"logit-{entry}.json").write_text(json.dumps(traffic))
+        (root / "bench" / "limits" / f"{name}.json").write_text(
+            json.dumps({"limits": LOGISTIC_LIMITS}))
+        bench["workloads"].append({"name": name, "config": config,
+                                   "traffic": f"logit-{entry}", "chips": chips, "why": "x"})
+        for m in bench["end_to_end"]:
+            if m["name"] == f"{entry}_s":
+                m["workloads"].append(name)
+        names.append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return names
+
+
 def make_tiny_root(dst: Path) -> Path:
     """A benchmark root at ``dst``: BENCHMARK.json (with the ``KEPT``
     cells), the bench code and limits as committed (``subopt``'s set for

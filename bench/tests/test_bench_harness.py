@@ -132,19 +132,18 @@ def _half_rows(X, y):
 def test_half_of_the_samples_left_out_is_not_correct(tiny_root, workload, monkeypatch):
     """The program's entry solves on half of the rows: the objective it
     reports is taken over the rest."""
-    import repro.core
-    from repro.core import path
+    from repro.core import engine, path
 
-    real_path, real_fit = path.fw_path_batched, repro.core.fw_solve
+    real_path, real_fit = path.fw_path_batched, engine.solve
 
     def half_path(X, y, *a, **k):
         return real_path(*_half_rows(X, y), *a, **k)
 
-    def half_fit(X, y, *a, **k):
-        return real_fit(*_half_rows(X, y), *a, **k)
+    def half_fit(oracle, X, y, *a, **k):
+        return real_fit(oracle, *_half_rows(X, y), *a, **k)
 
     monkeypatch.setattr(path, "fw_path_batched", half_path)
-    monkeypatch.setattr(repro.core, "fw_solve", half_fit)
+    monkeypatch.setattr(engine, "solve", half_fit)
     out = _run(tiny_root, workload)
     assert not out["correct"] and out["failed"] > 0
 
