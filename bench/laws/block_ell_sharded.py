@@ -36,6 +36,8 @@ from bench.draws import distinct_uniform, half_normal_scores
 
 KIND = "block_ell"
 PROBLEMS = ("lasso", "logistic")
+# the sizes a CPU test run holds: the same kinds of width, far fewer rows
+TINY = dict(m=200, p=2000, col_density=0.03, nnz_max=16, n_relevant=10)
 
 
 def _draw(key, *, mesh, m, p, nb_total, block_size, nnz_max, col_density,

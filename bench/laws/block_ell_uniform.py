@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from bench.draws import distinct_uniform, half_normal_scores
 
 KIND = "block_ell"
+# the sizes a CPU test run holds: the same kinds of width, far fewer rows
+TINY = dict(m=300, p=6000, col_density=0.02, nnz_max=24, n_relevant=12)
 
 
 @functools.partial(

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from bench.draws import distinct_uniform, half_normal_scores
 
 KIND = "dense"
+# the sizes a CPU test run holds: the same kinds of width, far fewer rows
+TINY = dict(m=40, p=3000, n_relevant=10)
 
 
 @functools.partial(

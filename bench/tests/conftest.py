@@ -12,12 +12,8 @@ for _p in (ROOT, ROOT / "src"):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-# each law's sizes for the CPU: widths of the same kinds, far fewer rows
-TINY = {
-    "block_ell_uniform": dict(m=300, p=6000, col_density=0.02, nnz_max=24,
-                              n_relevant=12),
-    "lowrank_dense": dict(m=40, p=3000, n_relevant=10),
-}
+from bench import harness  # noqa: E402
+
 TINY_POINTS = 20
 # At these sizes the sampled set holds 30-60 columns, and the program's
 # answers read shortfall 1.07-1.57 (CPU readings, four seeds a cell); an
@@ -84,45 +80,73 @@ LOGISTIC_LIMITS = {"shortfall": 2.0, "obj_err": 1e-5, "gap_err": 1e-5,
                    "l1_excess": 1e-3}
 
 
+# webspam trigram as l1-logistic (LIBSVM; Yuan, Ho & Lin, JMLR 13 (2012)),
+# 350,000 x 16,609,143, laid over the four-chip host: the shape of the
+# four-chip configuration a later change commits, at its published size
+WEBSPAM_SPEC = dict(LOGISTIC_SPEC, name="webspam-trigram", m=350000, p=16609143,
+                    nnz_max=128, col_density=78.6 / 350000, path_points=4,
+                    mesh={"data": 1, "model": 4})
+WEBSPAM_CELLS = {"webspam-trigram.path-k194": "path-k194-2lanes",
+                 "webspam-trigram.fit-k194": "fit-k194"}
+
+
+def commit_config(root: Path, spec: dict, cells: dict, chips: int, limits: dict) -> None:
+    """Write the configuration ``spec`` and its cells (name -> traffic)
+    into the benchmark root at ``root`` as a change that adds them does:
+    new files under bench/ and new BENCHMARK.json entries."""
+    config = spec["name"]
+    (root / "bench" / "configs" / f"{config}.json").write_text(json.dumps(spec))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": config, "source": "x", "reduced": [],
+                             "file": f"bench/configs/{config}.json", "why": "x"})
+    for name, traffic in cells.items():
+        (root / "bench" / "limits" / f"{name}.json").write_text(json.dumps({"limits": limits}))
+        bench["workloads"].append({"name": name, "config": config, "traffic": traffic,
+                                   "chips": chips, "why": "x"})
+        entry = json.loads((root / "bench" / "traffic" / f"{traffic}.json").read_text())["entry"]
+        for m in bench["end_to_end"]:
+            if m["name"] == f"{entry}_s":
+                m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
 def add_logistic_cells(root: Path, chips: int) -> list:
     """Add the l1-logistic cells to the benchmark root at ``root`` as new
     files and BENCHMARK.json entries, on a (1, chips) mesh; returns the
     cells' names."""
     config = f"l1logit-{chips}"
-    spec = dict(LOGISTIC_SPEC, name=config, mesh={"data": 1, "model": chips})
-    (root / "bench" / "configs" / f"{config}.json").write_text(json.dumps(spec))
-    bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["configs"].append({"name": config, "source": "x", "reduced": [],
-                             "file": f"bench/configs/{config}.json", "why": "x"})
-    names = []
+    cells = {}
     for entry, traffic in LOGISTIC_TRAFFIC.items():
-        name = f"{config}.{entry}"
         (root / "bench" / "traffic" / f"logit-{entry}.json").write_text(json.dumps(traffic))
-        (root / "bench" / "limits" / f"{name}.json").write_text(
-            json.dumps({"limits": LOGISTIC_LIMITS}))
-        bench["workloads"].append({"name": name, "config": config,
-                                   "traffic": f"logit-{entry}", "chips": chips, "why": "x"})
-        for m in bench["end_to_end"]:
-            if m["name"] == f"{entry}_s":
-                m["workloads"].append(name)
-        names.append(name)
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    return names
+        cells[f"{config}.{entry}"] = f"logit-{entry}"
+    spec = dict(LOGISTIC_SPEC, name=config, mesh={"data": 1, "model": chips})
+    commit_config(root, spec, cells, chips, LOGISTIC_LIMITS)
+    return list(cells)
 
 
-def make_tiny_root(dst: Path) -> Path:
-    """A benchmark root at ``dst``: BENCHMARK.json (with the ``KEPT``
-    cells), the bench code and limits as committed (``subopt``'s set for
-    these sizes), the configurations and paths shrunk."""
-    shutil.copy(ROOT / "BENCHMARK.json", dst / "BENCHMARK.json")
-    shutil.copytree(ROOT / "bench", dst / "bench",
+def copy_root(dst: Path, src: Path = ROOT) -> Path:
+    """The benchmark's files of the root at ``src`` (BENCHMARK.json and
+    bench/, its tests left out) copied to ``dst``."""
+    shutil.copy(src / "BENCHMARK.json", dst / "BENCHMARK.json")
+    shutil.copytree(src / "bench", dst / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    return dst
+
+
+def make_tiny_root(dst: Path, src: Path = ROOT) -> Path:
+    """A benchmark root at ``dst`` from the one at ``src``: BENCHMARK.json
+    (with the ``KEPT`` cells), the bench code as committed, the limits
+    with ``TINY_LIMITS`` for these sizes, the configurations and paths
+    shrunk. Each configuration takes the sizes its law states for a CPU
+    test run (``TINY`` in ``bench/laws/<law>.py``) and keeps the rest,
+    its mesh among them."""
+    copy_root(dst, src)
     bench = json.loads((dst / "BENCHMARK.json").read_text())
     _add_kept(bench, dst)
     (dst / "BENCHMARK.json").write_text(json.dumps(bench))
     for path in (dst / "bench" / "configs").glob("*.json"):
         spec = json.loads(path.read_text())
-        spec.update(TINY[spec["law"]])
+        spec.update(harness.law(dst, spec).TINY)
         path.write_text(json.dumps(spec))
     for lim in (dst / "bench" / "limits").glob("*.json"):
         doc = json.loads(lim.read_text())

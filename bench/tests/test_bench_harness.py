@@ -10,6 +10,8 @@ import numpy as np
 import pytest
 
 from bench import harness, run
+from bench.tests.conftest import (LOGISTIC_LIMITS, ROOT, commit_config, copy_root,
+                                  make_tiny_root)
 
 ARGS = ["--seed", "2147483659", "--seconds", "0.5", "--trace", "0"]
 
@@ -207,3 +209,44 @@ def test_new_files_are_found_without_edits(tiny_root, tmp_path):
     assert out["metrics"] == {"points_per_path.path": {"value": 10.0, "unit": "points"}}
     for p, content in before.items():
         assert p.read_bytes() == content
+
+
+LAWS = sorted(p.stem for p in (ROOT / "bench" / "laws").glob("*.py"))
+
+
+def _files(root):
+    return {str(p.relative_to(root)) for p in root.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+@pytest.mark.parametrize("law", LAWS)
+def test_tiny_root_shrinks_a_committed_configuration_of_each_law(tiny_root, tmp_path, law):
+    """A configuration of any law, committed under bench/configs with its
+    BENCHMARK.json entries, shrinks to its law's own test sizes and keeps
+    its other keys (its mesh among them); every other file of the tiny
+    root is what it is without it, byte for byte."""
+    src, dst = tmp_path / "src", tmp_path / "dst"
+    src.mkdir()
+    dst.mkdir()
+    copy_root(src)
+    tiny = harness.law(src, {"law": law}).TINY
+    spec = {"name": f"big-{law}", "law": law, "reference": "bench/reference.py",
+            "coef_scale": 2.5, "mesh": {"data": 1, "model": 4}}
+    spec.update({k: v * 1000 if isinstance(v, int) else v / 10 for k, v in tiny.items()})
+    cell = f"big-{law}.fit-k194"
+    commit_config(src, spec, {cell: "fit-k194"}, 4, LOGISTIC_LIMITS)
+    make_tiny_root(dst, src=src)
+
+    assert json.loads((dst / f"bench/configs/big-{law}.json").read_text()) == {**spec, **tiny}
+    new = {f"bench/configs/big-{law}.json", f"bench/limits/{cell}.json"}
+    assert _files(dst) == _files(tiny_root) | new
+    for f in _files(tiny_root) - {"BENCHMARK.json"}:
+        assert (dst / f).read_bytes() == (tiny_root / f).read_bytes(), f
+    bench = json.loads((dst / "BENCHMARK.json").read_text())
+    assert [w["name"] for w in bench["workloads"] if w["config"] == f"big-{law}"] == [cell]
+    bench["configs"] = [c for c in bench["configs"] if c["name"] != f"big-{law}"]
+    bench["workloads"] = [w for w in bench["workloads"] if w["name"] != cell]
+    for m in bench["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"] = [w for w in m["workloads"] if w != cell]
+    assert bench == json.loads((tiny_root / "BENCHMARK.json").read_text())

@@ -151,6 +151,36 @@ def test_logistic_reference_optimum_matches_a_long_plain_frank_wolfe(logit):
         assert fr <= f_fw * (1 + 1e-6)
 
 
+def test_logistic_reference_grows_its_working_set_to_the_violations(monkeypatch):
+    """A design whose optimum's support is many times the first working
+    set certifies under GAP_TARGET of f(0) in at most 3 rounds and 3
+    compiles of the working-set solve: the set grows to the columns that
+    break the l1 ball's optimality conditions, not by doubling, and each
+    round's solve stops on its own gap."""
+    spec = dict(SPEC, m=2000, p=4000, col_density=0.01, n_relevant=60)
+    data = block_ell_sharded.generate(spec, jax.random.key(2**31 + 9), mesh=_mesh())
+    dmax = 0.5 * float(np.abs(np.asarray(data["coef"])).sum())
+    first = 8
+    monkeypatch.setattr(reference_logistic, "WORK_SET", first)
+    solve = reference_logistic._restricted
+    rounds = []
+
+    def counted(*a):
+        rounds.append(solve(*a))
+        return rounds[-1]
+
+    monkeypatch.setattr(reference_logistic, "_restricted", counted)
+    compiled = solve._cache_size()
+    f_ref, g_ref = reference_logistic.optimum(data, np.geomspace(dmax / 100, 3 * dmax, 4))
+    support = int(np.max(np.sum(np.asarray(rounds[-1][0]) != 0, axis=1)))
+    assert support >= 8 * first
+    assert len(rounds) <= 3 and solve._cache_size() - compiled <= 3
+    # each round's solve stopped on its own gap, not at its cap
+    assert all(np.all(np.asarray(steps) < reference_logistic.MAX_STEPS) for _, steps in rounds)
+    f0 = reference_logistic.f_zero(data)
+    assert np.all(g_ref <= reference_logistic.GAP_TARGET * f0) and np.all(f_ref < f0)
+
+
 @pytest.fixture(scope="module")
 def logit_root(tiny_root, tmp_path_factory):
     root = tmp_path_factory.mktemp("logit_root")

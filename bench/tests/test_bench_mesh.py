@@ -2,7 +2,9 @@
 virtual CPU devices in a subprocess (the test process keeps one device):
 its data is drawn over a (1, 4) mesh with no device making the whole
 design, and its path and fit entries run through the program's mesh
-driver and come out correct."""
+driver and come out correct. A webspam-shaped four-chip configuration
+committed under bench/configs at its published size shrinks with the
+tiny root, and its path and fit cells come out correct too."""
 import json
 import os
 import subprocess
@@ -23,7 +25,9 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     from bench import run
     from bench.laws import block_ell_sharded, block_ell_uniform
-    from bench.tests.conftest import make_tiny_root, add_logistic_cells
+    from bench.tests.conftest import (LOGISTIC_LIMITS, WEBSPAM_CELLS, WEBSPAM_SPEC,
+                                      add_logistic_cells, commit_config, copy_root,
+                                      make_tiny_root)
     from repro import distributed
 
     out = {}
@@ -60,6 +64,19 @@ SCRIPT = textwrap.dedent("""
                      "metrics": sorted(res["metrics"]), "checks": res["checks"]}
     out["unchanged"] = all(Path(p).read_bytes() == b for p, b in before.items()
                            if Path(p).name != "BENCHMARK.json")
+
+    # webspam trigram's four-chip configuration committed at its published
+    # size into a copy of the root, then shrunk with the rest of the root
+    src = copy_root(Path(tempfile.mkdtemp()))
+    commit_config(src, WEBSPAM_SPEC, WEBSPAM_CELLS, 4, LOGISTIC_LIMITS)
+    tiny = make_tiny_root(Path(tempfile.mkdtemp()), src=src)
+    out["webspam_spec"] = json.loads((tiny / "bench/configs/webspam-trigram.json").read_text())
+    for name in WEBSPAM_CELLS:
+        res = run.run(["--workload", name, "--seed", "3000000043", "--seconds", "0",
+                       "--trace", "0"], root=tiny, require_tpu=False)
+        out[name] = {"correct": res["correct"], "attempted": res["attempted"],
+                     "failed": res["failed"], "count": res["device"]["count"],
+                     "metrics": sorted(res["metrics"]), "checks": res["checks"]}
     print("RESULT" + json.dumps(out))
 """)
 
@@ -98,3 +115,20 @@ def test_four_chip_logistic_cell_from_new_files_is_correct(four_devices, entry):
 
 def test_adding_the_cell_changes_no_existing_file(four_devices):
     assert four_devices["unchanged"]
+
+
+@pytest.mark.parametrize("entry", ["path", "fit"])
+def test_committed_webspam_shaped_four_chip_cell_is_correct(four_devices, entry):
+    """A four-chip configuration of law block_ell_sharded committed under
+    bench/configs, as the webspam-trigram cells will be, shrinks with the
+    tiny root to its law's test sizes, keeps its mesh, and its path
+    (traffic path-k194-2lanes) and fit cells run correct on four devices."""
+    from bench.laws import block_ell_sharded
+
+    spec = four_devices["webspam_spec"]
+    assert spec["mesh"] == {"data": 1, "model": 4}
+    assert {k: spec[k] for k in block_ell_sharded.TINY} == block_ell_sharded.TINY
+    r = four_devices[f"webspam-trigram.{entry}-k194"]
+    assert r["correct"], r["checks"]
+    assert r["failed"] == 0 and r["attempted"] == 4 and r["count"] == 4
+    assert r["metrics"] == sorted(["setup_s", f"{entry}_s"])
